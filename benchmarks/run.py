@@ -4,10 +4,12 @@
 
 Prints ``name,us_per_call,derived`` CSV per benchmark.  ``--full`` runs the
 larger sweeps (the default is sized for CI).  ``--devices N`` caps the
-sharded weak-scaling sweep's device counts (subprocesses with N forced
-host devices; default 4, 0 skips the sweep).  The dry-run roofline table
-is produced separately by repro.launch.dryrun (512 fake devices) and read
-back here if present.
+sharded weak-scaling sweep's device counts (default 4, 0 skips the
+sweep); the sweep runs in this process on the devices JAX has, so on the
+CPU set ``XLA_FLAGS=--xla_force_host_platform_device_count=4`` before
+starting.  The dry-run roofline table is produced separately by
+repro.launch.dryrun (512 fake devices) and read back here if present.
+Any failing phase raises, so the run exits non-zero.
 
 Every CSV row is also dumped to ``BENCH_kernels.json`` next to the repo
 root, so successive PRs leave a machine-readable perf trajectory.
@@ -21,7 +23,7 @@ import pathlib
 import sys
 import time
 
-BENCH_JSON = pathlib.Path(__file__).resolve().parent.parent /     "BENCH_kernels.json"
+BENCH_JSON = pathlib.Path(__file__).resolve().parent.parent / "BENCH_kernels.json"
 
 
 def merge_bench_rows(rows: list, path: pathlib.Path = BENCH_JSON) -> list:
@@ -120,6 +122,8 @@ def main() -> None:
     devices = 4
     if "--devices" in sys.argv:
         devices = int(sys.argv[sys.argv.index("--devices") + 1])
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from . import (autotune_bench, cascade_bench, fig4_sweep,
                    fig5_nonidealities, kernel_bench, reliability_bench,
                    serve_bench, sharded_bench, sharded_perf,
@@ -149,21 +153,18 @@ def main() -> None:
         _run_and_collect(lambda: sharded_bench.main(devices), rows)
 
     # roofline summary (if the dry-run has produced results)
-    try:
-        from . import roofline_table
-        cells = roofline_table.load("baseline", "single")
-        if cells:
-            bounds = {}
-            for e in cells:
-                b = e["roofline"]["bottleneck"]
-                bounds[b] = bounds.get(b, 0) + 1
-            emit("dryrun_cells_single", 0,
-                 f"n={len(cells)}_bottlenecks={bounds}")
-        cells_m = roofline_table.load("baseline", "multi")
-        if cells_m:
-            emit("dryrun_cells_multi", 0, f"n={len(cells_m)}")
-    except Exception as e:  # pragma: no cover
-        emit("dryrun_cells", 0, f"unavailable({e})")
+    from . import roofline_table
+    cells = roofline_table.load("baseline", "single")
+    if cells:
+        bounds = {}
+        for e in cells:
+            b = e["roofline"]["bottleneck"]
+            bounds[b] = bounds.get(b, 0) + 1
+        emit("dryrun_cells_single", 0,
+             f"n={len(cells)}_bottlenecks={bounds}")
+    cells_m = roofline_table.load("baseline", "multi")
+    if cells_m:
+        emit("dryrun_cells_multi", 0, f"n={len(cells_m)}")
 
     if full:
         res = fig4_sweep.run()

@@ -8,21 +8,20 @@ single-device (1-bank mesh) reference over the *same* N-shard dataset —
 ``speedup`` is therefore the cross-device parallelism win on identical
 data, and ``match`` asserts the merge stayed bit-identical.
 
-Device counts need ``XLA_FLAGS=--xla_force_host_platform_device_count``
-set before jax initializes, so the parent spawns one worker subprocess
-per point:
+Every sweep point runs in this one process, on the devices JAX already
+has: the point-code and ACAM rows for each device count in
+``DEVICE_SWEEP`` up to ``--devices`` and up to ``len(jax.devices())``.
+Nothing is spawned, so on a TPU host the process that holds the chips
+measures them.  On the CPU, give JAX host devices before it starts:
 
-    PYTHONPATH=src python -m benchmarks.sharded_bench [--devices N]
-    PYTHONPATH=src python -m benchmarks.sharded_bench --worker N  (internal)
+    XLA_FLAGS=--xla_force_host_platform_device_count=4 \
+        PYTHONPATH=src python -m benchmarks.sharded_bench [--devices N]
 
-Interpret-mode CPU numbers are a proxy (the container has no TPU): the
-structural claim is that per-device work is fixed while total rows grow.
+A failing point raises, so the run exits non-zero.  Timings on the CPU
+are Pallas interpret-mode wall clock, not device numbers.
 """
 from __future__ import annotations
 
-import os
-import pathlib
-import subprocess
 import sys
 import time
 
@@ -35,8 +34,8 @@ DEVICE_SWEEP = (1, 2, 4)
 
 
 def worker(n_devices: int) -> None:
-    """One sweep point (runs in a subprocess with N host devices): the
-    point-code (mcam/l2) row and the ACAM range-search row, both at fixed
+    """One sweep point on ``n_devices`` local devices: the point-code
+    (mcam/l2) row and the ACAM range-search row, both at fixed
     rows/device."""
     import jax
     import jax.numpy as jnp
@@ -116,44 +115,17 @@ def worker(n_devices: int) -> None:
 
 
 def main(max_devices: int = 4) -> None:
-    """Spawn one worker per device count <= ``max_devices``, echo CSV."""
-    root = pathlib.Path(__file__).resolve().parent.parent
+    """Run every sweep point this process has devices for, in-process."""
+    import jax
+
+    have = len(jax.devices())
     for n in DEVICE_SWEEP:
-        if n > max_devices:
-            continue
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
-        env["JAX_PLATFORMS"] = "cpu"    # skip the libtpu-init stall
-        env["PYTHONPATH"] = (str(root / "src") + os.pathsep
-                             + env.get("PYTHONPATH", ""))
-        proc = subprocess.run(
-            [sys.executable, "-m", "benchmarks.sharded_bench",
-             "--worker", str(n)],
-            env=env, cwd=str(root), capture_output=True, text=True,
-            timeout=1800)
-        # forward whatever rows the worker managed to print; only rows it
-        # never reached are marked failed (a crash in the later ACAM
-        # measurement must not discard the point-code result)
-        printed = set()
-        for line in proc.stdout.splitlines():
-            for prefix in ("kernel_cam_search_sharded",
-                           "kernel_acam_range_sharded"):
-                if line.startswith(prefix):
-                    printed.add(prefix)
-                    print(line)
-        if proc.returncode != 0:
-            err = proc.stderr.strip()[-200:]
-            for prefix in ("kernel_cam_search_sharded",
-                           "kernel_acam_range_sharded"):
-                if prefix not in printed:
-                    print(f"{prefix}_d{n},0,failed({err!r})")
+        if n <= min(max_devices, have):
+            worker(n)
 
 
 if __name__ == "__main__":
-    if "--worker" in sys.argv:
-        worker(int(sys.argv[sys.argv.index("--worker") + 1]))
-    else:
-        devs = 4
-        if "--devices" in sys.argv:
-            devs = int(sys.argv[sys.argv.index("--devices") + 1])
-        main(devs)
+    devs = 4
+    if "--devices" in sys.argv:
+        devs = int(sys.argv[sys.argv.index("--devices") + 1])
+    main(devs)

@@ -68,6 +68,9 @@ def main(argv: Optional[list] = None) -> int:
     import jax.numpy as jnp
 
     from repro.core import CAMASim
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     sim = CAMASim.from_json(args.config)
     cfg = sim.config

@@ -143,7 +143,7 @@ class FunctionalSimulator:
         # the exact-integer fast path is also gated on reliability off)
         self.int_codes = (
             app.data_bits
-            if (self.pipeline and app.data_bits and app.data_bits <= 8
+            if (self.pipeline and app.data_bits and app.data_bits <= 7
                 and app.distance in ("hamming", "l1", "l2", "dot")
                 and dev.variation == "none" and circ.cell_type != "acam"
                 and not config.reliability.enabled)

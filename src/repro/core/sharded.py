@@ -44,7 +44,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.launch.mesh import compat_shard_map, make_cam_mesh
+from repro.launch.mesh import make_cam_mesh
 from . import merge, prefilter, variation
 from .config import CAMConfig
 from .functional import (CAMState, FunctionalSimulator,
@@ -366,11 +366,11 @@ class ShardedCAMSimulator:
                 return self._combine_selected(dist, match, local_ids,
                                               b_idx, nv_loc, R, K_pad, k)
 
-            return compat_shard_map(
+            return jax.shard_map(
                 body, mesh=self.mesh,
                 in_specs=(P(ba), P(ba), P(ba), P(), q_spec, q_spec, q_spec,
                           P()),
-                out_specs=(q_spec, q_spec))(
+                out_specs=(q_spec, q_spec), check_vma=False)(
                 state.grid, state.row_valid, state.sigs, state.col_valid,
                 qseg, qsig, qvalid, key)
 
@@ -382,10 +382,13 @@ class ShardedCAMSimulator:
                 cycle_keys=cycle_keys_for(key))
             return self._combine(dist, match, b_idx, nv_loc, R, K_pad, k)
 
-        return compat_shard_map(
+        # the merged results are replicated over the bank axis by the
+        # all_gather + re-rank; check_vma=False because the varying-axes
+        # inference cannot see that through the comparator re-rank
+        return jax.shard_map(
             body, mesh=self.mesh,
             in_specs=(P(ba), P(ba), P(), q_spec, P()),
-            out_specs=(q_spec, q_spec))(
+            out_specs=(q_spec, q_spec), check_vma=False)(
             state.grid, state.row_valid, state.col_valid, qseg, key)
 
     def _combine(self, dist, match, b_idx, nv_loc: int, R: int,

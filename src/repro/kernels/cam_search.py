@@ -1,74 +1,51 @@
-"""Pallas TPU kernels: tiled CAM subarray search (single-query and batched).
+"""Pallas TPU kernels: tiled CAM subarray search with a fused sense amplifier.
 
-TPU adaptation of the CAM array (DESIGN.md §2): each grid step loads one
-(R, C) subarray tile from HBM into VMEM — the analogue of the data resident
-in a physical CAM array — evaluates the match-line reduction against the
-query segment(s), and reduces along the column axis.  The grid iterates the
-(nv, nh) subarray mesh, exactly the partition produced by the mapping
-submodule.
+TPU adaptation of the CAM array (DESIGN.md §2): a (R, C) subarray tile sits
+in VMEM — the analogue of the data resident in a physical CAM array — and is
+searched by a whole query tile at once; the grid walks the (nv, nh)
+subarray mesh produced by the mapping submodule.
 
-Two kernels:
+One kernel body (``_fused_kernel``) serves every entry point.  Per tile it
+computes the distance block (``_dist_block_batched`` / the ACAM
+``_range_block_batched``), masks padding rows to +inf and runs the
+sense-amplifier model of ``core.subarray.sense`` (exact / best / threshold,
+with the intra-subarray winner-take-all for best) while the block is still
+in VMEM (``_tile_fused``).  ``cam_fused_reference`` is its pure-jnp twin,
+built from the same tile function.
 
-``cam_search_pallas`` — the original single-query kernel.  Per grid step
-(i, j) it broadcasts one (C,) query segment across the rows on the VPU:
+Distance formulation: ``l2``/``dot`` are shaped for the MXU — the cross
+term is a (Qt, C) x (C, R) matmul and the column mask is folded into the
+row/query norms (‖s‖² − 2·S·Qᵀ + ‖q‖²).  ``l1``/``hamming``/``range`` have
+no matmul form and compare a (Qt, R, C) block on the VPU.  Noise-free
+integral codes run on int8 operands (int32 MXU accumulation) and 1-bit
+hamming codes bit-packed into uint32 words (XOR + popcount).
 
-    stored    (1, 1, R, C)  VMEM   <- HBM tile (i, j)
-    query     (1, C)        VMEM   <- segment j (revisited across i)
-    col_valid (1, C)        VMEM
-    out       (1, 1, R)     VMEM   -> dist tile (i, j)
+Grids (``_fused_driver``), both with the Q-tile axis innermost so the
+stored block stays resident while every query tile passes over it:
 
-``cam_search_batched_pallas`` — the query-batched kernel (store once,
-search many; paper Fig. 1b).  The grid becomes (nv, nh, Q/Qt) with the
-Q-tile axis innermost, so a stored tile's BlockSpec index (i, j) is constant
-across consecutive steps: Pallas keeps the (R, C) tile resident in VMEM and
-each stored tile is streamed from HBM **once per full query batch** instead
-of once per query (the vmap-of-single-query path re-streams the whole grid
-Q times).  Per grid step (i, j, k):
+    bank-blocked (default)   grid (nv/vb, Q/Qt)
+        stored     (vb, nh, R, C)  <- HBM bank block b, once per batch
+        queries    (nh, Qt, C)     <- Q-tile k
+        col_valid  (nh, 1, C)
+        row_valid  (vb, 1, R)
+        dist/match (vb, nh, Qt, R) -> bank-major (nv, nh, Q, R) outputs
 
-    stored    (1, 1, R, C)  VMEM   <- HBM tile (i, j); resident across k
-    queries   (Qt, 1, C)    VMEM   <- Q-tile k, segment j
-    col_valid (1, C)        VMEM
-    out       (Qt, 1, 1, R) VMEM   -> dist tile (k, i, j)
+    per-tile (pipeline=False, or one bank over budget)  grid (nv, nh, Q/Qt)
+        the same blocks with vb = nh = 1
 
-VMEM working set per step: 4·(R·C + Qt·C + C + Qt·R) bytes (f32).  For the
-default Qt = 32 and a 64×64 subarray that is ~32 KiB — far below the ~16 MiB
-VMEM budget, so Qt can be raised until either the (Qt, C) query tile or the
-(Qt, R) output tile approaches the (R, C) stored tile in size; past that the
-kernel stops being stored-stream-bound and larger tiles buy nothing.
+The last two dims of every block are whole array dims or (8, 128)-aligned,
+which Mosaic requires, and no block carries a size-1 sublane dim.  The
+body walks its vb·nh tiles in a ``fori_loop``, so compile time and live
+temporaries do not grow with the block.  ``fused_vmem_bytes`` counts what a
+grid step holds in VMEM; ``resident_banks``/``choose_q_tile`` size the
+blocks against the device's budget (``DEVICE_MODELS``), which the compiled
+kernel passes to Mosaic as ``vmem_limit_bytes``.
 
-Distance formulation: for ``l2``/``dot`` the batched kernel is shaped for
-the MXU — the cross term is a (Qt, C) × (C, R) matmul and the masked column
-weights are folded into the row/query norms (‖s‖² − 2·S·Qᵀ + ‖q‖², all
-norms computed over valid columns only).  ``l1``/``hamming`` have no matmul
-form and keep the VPU broadcast-compare-reduce path, materializing a
-(Qt, R, C) block in registers.
-
-``cam_search_fused_pallas`` — batched search + fused sense-and-reduce
-epilogue.  The sense-amplifier model of ``core.subarray.sense`` (exact /
-best / threshold) and the intra-subarray winner-take-all reduction
-(min over the R match lines) run inside the kernel while the distance block
-is still in VMEM.  With ``want_dist=False`` only the digital match lines are
-written back, so the (Q, nv, nh, R) float distance tensor never hits HBM —
-this is the common exact/threshold AND-merge path, where the merge consumes
-match lines only.
-
-``cam_range_fused_pallas`` — the ACAM variant of the fused batched kernel
-(paper §III-C, Table III: analog cells store a [lo, hi] range per cell; the
-memristor / complementary-FeFET ACAMs are the hardware targets).  The
-"distance" is the range-violation count of ``core.distance.range_violations``
-— #cells whose stored interval excludes the query value — and the same
-exact/best/threshold sense epilogue runs on it in-kernel.  The 5-D
-(nv, nh, R, C, 2) range grid is NOT blocked as a 5-D ref: the caller splits
-the trailing [lo, hi] dim before ``pallas_call`` and the kernel takes two
-dense (R, C) planes per tile, so the lane (last) dimension of every block
-stays the dense C axis the VPU wants.  Per grid step (i, j, k):
-
-    lo, hi    (1, 1, R, C)  VMEM  <- HBM tiles (i, j); resident across k
-    queries   (Qt, 1, C)    VMEM  <- Q-tile k, segment j
-    out       (Qt, 1, 1, R) VMEM  -> violation-count / match tile (k, i, j)
-
-The violation compare-and-count has no matmul form (like l1/hamming) and
-materializes a (Qt, R, C) block in registers on the VPU.
+``cam_search_fused_pallas`` (point codes) and ``cam_range_fused_pallas``
+(ACAM [lo, hi] planes, split by the caller so each plane keeps a dense
+lane dim) return ``(dist, match)`` each (Q, nv, nh, R), or match alone
+with ``want_dist=False``.  ``cam_search_batched_pallas`` /
+``cam_search_pallas`` return the distance output alone.
 """
 from __future__ import annotations
 
@@ -87,10 +64,9 @@ _INF = float("inf")
 # (Qt, R, C) register blocks of the VPU distances still fit.
 VMEM_BUDGET_BYTES = 4 * 1024 * 1024
 
-# Pipelined-driver budget: the bank-blocked grid keeps whole stored bank
-# blocks resident across Q-tiles (half the budget) plus the in-flight
-# query/output tiles (the other half), closer to the ~16 MiB physical VMEM
-# than the per-tile formula's conservative 4 MiB.
+# Pipelined-driver VMEM budget in interpret mode (the "cpu" entry of
+# DEVICE_MODELS below, and the default of the model functions): what one
+# grid step may hold — stored bank block, query/output tiles, temporaries.
 RESIDENT_BUDGET_BYTES = 12 * 1024 * 1024
 
 # Per-grid-step dispatch overhead (seconds) for the measured-model Q-tile
@@ -105,6 +81,39 @@ STEP_OVERHEAD_S = float(os.environ.get("CAMASIM_STEP_OVERHEAD_S", 2e-4))
 # Nominal HBM bandwidth for the traffic term of the Q-tile model; the same
 # constant plan.autotune.simulated_qps uses (bytes/s).
 HBM_BYTES_PER_S = 819e9
+
+# Kernel-model rates per device, keyed by ``jax.Device.device_kind``:
+# ``hbm_bytes_per_s`` feeds the Q-tile model's traffic term and
+# ``vmem_budget_bytes`` bounds what one grid step of the fused kernels may
+# hold in VMEM (every double-buffered block plus the per-tile temporaries,
+# ``fused_vmem_bytes``); the compiled kernels pass it on as Mosaic's
+# ``vmem_limit_bytes``.  TPU v5e ("TPU v5 lite"): 819 GB/s HBM (Google
+# Cloud documentation, "TPU v5e"); the 32 MiB budget is a quarter of the
+# chip's VMEM.  The "cpu" entry serves interpret mode, where both numbers
+# only rank schedules.  A device kind missing here is an error, not a
+# default (``device_model``).
+DEVICE_MODELS = {
+    "cpu": {"hbm_bytes_per_s": HBM_BYTES_PER_S,
+            "vmem_budget_bytes": RESIDENT_BUDGET_BYTES},
+    "TPU v5 lite": {"hbm_bytes_per_s": 819e9,
+                    "vmem_budget_bytes": 32 * 1024 * 1024},
+}
+
+
+def _device_kind() -> str:
+    return jax.devices()[0].device_kind
+
+
+def device_model(kind: Optional[str] = None) -> dict:
+    """Kernel-model rates for ``kind`` (default: the first local device)."""
+    kind = _device_kind() if kind is None else kind
+    try:
+        return DEVICE_MODELS[kind]
+    except KeyError:
+        raise ValueError(
+            f"no kernel model for device kind {kind!r}; add its HBM rate "
+            f"and VMEM budget to cam_search.DEVICE_MODELS "
+            f"(known: {sorted(DEVICE_MODELS)})") from None
 
 # Ceiling on the per-step VPU broadcast block (qt, vb·segs·R, C) that the
 # no-matmul distances (l1 / unpacked hamming / ACAM range) materialize while
@@ -180,27 +189,65 @@ def default_q_tile(rows: int, cols: int, planes: int = 1, *,
     return max(1, 1 << (int(qt).bit_length() - 1))
 
 
+def _ceil_to(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def fused_vmem_bytes(vb: int, qt: int, segs: int, rows: int, cols: int,
+                     planes: int = 1, *, itemsize: int = 4,
+                     out_planes: int = 2, bcast_cols: int = 0) -> int:
+    """VMEM one grid step of the fused kernels holds, in bytes.
+
+    Counts every block Pallas double-buffers — the (vb, segs, R, C)
+    stored planes, the (segs, qt, C) query tile, the (segs, 1, C)
+    col_valid and (vb, 1, R) row_valid masks and the (vb, segs, qt, R)
+    output tiles — each padded to the (sublane, 128-lane) VMEM tiling
+    (8 sublanes for 32-bit, 32 for int8), plus the temporaries of the one
+    (R, C) tile the body evaluates at a time: the f32 copy of the stored
+    tile, a few (qt, R) distance/match blocks and, for distances without
+    a matmul form, the (qt, R, bcast_cols) compare block.
+    """
+    sub = 8 * max(1, 4 // itemsize)
+    lanes = 128
+    stored = (planes * vb * segs * _ceil_to(rows, sub) * _ceil_to(cols, lanes)
+              * itemsize)
+    query = segs * _ceil_to(qt, sub) * _ceil_to(cols, lanes) * itemsize
+    masks = 4 * 8 * (segs * _ceil_to(cols, lanes) + vb * _ceil_to(rows, lanes))
+    out = out_planes * vb * segs * _ceil_to(qt, 8) * _ceil_to(rows, lanes) * 4
+    tile = 4 * (planes * _ceil_to(rows, 8) * _ceil_to(cols, lanes)
+                + 6 * _ceil_to(qt, 8) * _ceil_to(rows, lanes)
+                + (qt * _ceil_to(rows, 8) * _ceil_to(bcast_cols, lanes)
+                   if bcast_cols else 0))
+    return 2 * (stored + query + masks + out) + tile
+
+
 def resident_banks(banks: int, segs: int, rows: int, cols: int,
                    planes: int = 1, *, itemsize: int = 4,
                    budget_bytes: int = RESIDENT_BUDGET_BYTES) -> int:
     """Bank-block size for the pipelined driver's VMEM-resident fast path.
 
-    Returns the largest divisor ``vb`` of ``banks`` whose
-    (vb, segs, rows, cols) stored planes fit the resident half of the
-    budget (the other half holds the double-buffered query/output tiles).
-    ``vb == banks`` means the WHOLE store stays on-chip and is streamed
-    from HBM once total — no re-stream per Q-tile; smaller ``vb`` still
-    streams the store exactly once per batch (block axis outermost) while
-    Pallas prefetches the next bank block during the current block's
-    distance math.  0 = not even one bank fits; the caller falls back to
-    the per-(R, C)-tile grid.
+    Returns the largest divisor ``vb`` of ``banks`` whose double-buffered
+    (vb, segs, rows, cols) stored planes and row masks fit a quarter of
+    the budget (the rest holds the (vb, segs, qt, R) output tiles, which
+    outweigh the stored block from qt > C/2 on, and the query tile and
+    tile temporaries; ``choose_q_tile`` sizes those against the whole
+    ``fused_vmem_bytes`` count).  ``vb == banks`` means the WHOLE store
+    stays on-chip and is streamed from HBM once total; smaller ``vb``
+    still streams the store exactly once per batch (block axis outermost)
+    while Pallas prefetches the next bank block during the current
+    block's distance math.  0 = not even one bank fits; the caller then
+    runs the per-(R, C)-tile grid.
     """
-    half = budget_bytes // 2
-    per_bank = planes * segs * rows * cols * itemsize
-    if per_bank <= 0 or per_bank > half or banks < 1:
+    sub = 8 * max(1, 4 // itemsize)
+    # double-buffered stored planes + (1, R) row mask, per bank
+    per_bank = 2 * (planes * segs * _ceil_to(rows, sub)
+                    * _ceil_to(cols, 128) * itemsize
+                    + 4 * 8 * _ceil_to(rows, 128))
+    share = budget_bytes // 4
+    if banks < 1 or per_bank > share:
         return 0
     return max(v for v in range(1, banks + 1)
-               if banks % v == 0 and v * per_bank <= half)
+               if banks % v == 0 and v * per_bank <= share)
 
 
 def choose_q_tile(rows: int, cols: int, planes: int = 1, *, banks: int = 1,
@@ -213,21 +260,21 @@ def choose_q_tile(rows: int, cols: int, planes: int = 1, *, banks: int = 1,
 
     Walks the power-of-two ladder and scores every rung with the same
     HBM-traffic proxy ``plan.autotune.simulated_qps`` bills (stored-plane
-    stream + query stream + output write-back over a nominal bandwidth)
+    stream + query stream + output write-back over ``hbm_bytes_per_s``)
     PLUS a per-grid-step dispatch term — the cost interpret mode actually
     pays and the fixed formula ignored; ``benchmarks/kernel_bench.py``
-    validates the ranking against wall clock.  Rungs whose working set
-    (resident bank block + query tile + output tile) blows the budget are
-    infeasible.  The choice is per GEOMETRY, not per batch: the runtime
-    clamp ``qt = min(qt, Q)`` then makes per-call fixed overhead amortize
-    monotonically in Q (larger batches reuse the same block schedule over
-    more queries, which is the monotone-qps contract the Q-sweep rows
-    assert).
+    validates the ranking against wall clock.  Rungs whose VMEM count
+    (``fused_vmem_bytes``: resident bank block, query and output tiles,
+    tile temporaries) blows the budget are infeasible.  The choice is per
+    GEOMETRY, not per batch: the runtime clamp ``qt = min(qt, Q)`` then
+    makes per-call fixed overhead amortize monotonically in Q (larger
+    batches reuse the same block schedule over more queries, which is the
+    monotone-qps contract the Q-sweep rows assert).
 
     ``bcast_cols`` declares the lane width of the per-step VPU broadcast
     block for no-matmul distances (0 = no block: l2/dot run on the MXU and
     packed hamming reduces (Qt, R, W) with W = C/32 words).  When nonzero,
-    rungs whose (qt, bank-block rows, bcast_cols) compare block blows
+    rungs whose (qt, R, bcast_cols) compare block blows
     ``BCAST_BUDGET_BYTES`` are infeasible — the block dwarfs every streamed
     operand and growing it past the cache cliff is what made large-Q
     batches SLOWER per query (the throughput collapse this driver fixes).
@@ -243,22 +290,21 @@ def choose_q_tile(rows: int, cols: int, planes: int = 1, *, banks: int = 1,
     for qt in Q_TILES:
         nq = -(-int(Q) // qt)
         if vb:
-            blocks = banks // vb
-            block_bytes = (planes * vb * segs * rows * cols * itemsize
-                           + qt * segs * cols * itemsize
-                           + qt * vb * segs * rows * 4 * out_planes)
-            bcast_bytes = 4 * qt * vb * segs * rows * bcast_cols
-            steps = blocks * nq
+            need = fused_vmem_bytes(vb, qt, segs, rows, cols, planes,
+                                    itemsize=itemsize, out_planes=out_planes,
+                                    bcast_cols=bcast_cols)
+            steps = (banks // vb) * nq
             stream = stored                       # store on-chip once
-            q_bytes = itemsize * Q * segs * cols * blocks
+            q_bytes = itemsize * Q * segs * cols * (banks // vb)
         else:
-            block_bytes = (planes * rows * cols * itemsize
-                           + qt * cols * itemsize + qt * rows * 4 * out_planes)
-            bcast_bytes = 4 * qt * rows * bcast_cols
+            need = fused_vmem_bytes(1, qt, 1, rows, cols, planes,
+                                    itemsize=itemsize, out_planes=out_planes,
+                                    bcast_cols=bcast_cols)
             steps = banks * segs * nq
             stream = stored * nq                  # re-streamed per Q-tile
             q_bytes = itemsize * Q * segs * cols * banks
-        if block_bytes > budget_bytes or bcast_bytes > BCAST_BUDGET_BYTES:
+        bcast_bytes = 4 * qt * rows * bcast_cols
+        if need > budget_bytes or bcast_bytes > BCAST_BUDGET_BYTES:
             continue
         out_bytes = 4.0 * Q * banks * segs * rows * out_planes
         t = ((stream + q_bytes + out_bytes) / hbm_bytes_per_s
@@ -268,50 +314,18 @@ def choose_q_tile(rows: int, cols: int, planes: int = 1, *, banks: int = 1,
     return best
 
 
-def _dist_block(stored, q, valid, distance: str):
-    if distance == "hamming":
-        d = (stored != q).astype(jnp.float32)
-    elif distance == "l1":
-        d = jnp.abs(stored - q)
-    elif distance == "l2":
-        d = jnp.square(stored - q)
-    elif distance == "dot":
-        d = -(stored * q)
-    else:
-        raise ValueError(distance)
-    return jnp.sum(d * valid, axis=-1)
-
-
-def _kernel(stored_ref, query_ref, valid_ref, out_ref, *, distance: str):
-    stored = stored_ref[0, 0]          # (R, C)
-    q = query_ref[0]                   # (C,)
-    valid = valid_ref[0]               # (C,)
-    out_ref[0, 0] = _dist_block(stored, q[None, :], valid[None, :], distance)
-
-
 @functools.partial(jax.jit,
                    static_argnames=("distance", "interpret"))
 def cam_search_pallas(stored: jax.Array, query: jax.Array,
                       col_valid: jax.Array, *, distance: str = "l2",
                       interpret: bool = False) -> jax.Array:
     """stored (nv, nh, R, C), query (nh, C), col_valid (nh, C)
-    -> dist (nv, nh, R)."""
+    -> dist (nv, nh, R): the batched kernel at Q = 1."""
     nv, nh, R, C = stored.shape
     assert query.shape == (nh, C), (query.shape, (nh, C))
-    grid = (nv, nh)
-    return pl.pallas_call(
-        functools.partial(_kernel, distance=distance),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, R, C), lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec((1, C), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, C), lambda i, j: (j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, R), lambda i, j: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((nv, nh, R), jnp.float32),
-        interpret=interpret,
-    )(stored.astype(jnp.float32), query.astype(jnp.float32),
-      col_valid.astype(jnp.float32))
+    return cam_search_batched_pallas(stored, query[None], col_valid,
+                                     distance=distance,
+                                     interpret=interpret)[0]
 
 
 # ---------------------------------------------------------------------------
@@ -329,17 +343,35 @@ def packed_hamming_block(stored, q) -> jax.Array:
     return jnp.sum(jax.lax.population_count(x), axis=-1, dtype=jnp.int32)
 
 
+def _row_sq_norms(stored, valid) -> jax.Array:
+    """(R, C) f32 tile -> (R,) column-masked squared row norms.
+
+    Taken as an MXU product with a mask row rather than a lane reduction:
+    the reduction leaves the norms along sublanes, and broadcasting them
+    across the query rows of the distance block then costs Mosaic a
+    (Qt, R, C) VMEM temporary (16 MiB at Qt = 256, R = C = 128)."""
+    w = jnp.broadcast_to(valid[None, :], (8, stored.shape[1]))
+    return jax.lax.dot_general(
+        w, stored * stored, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)[0]
+
+
 def _dist_block_batched(stored, q, valid, distance: str) -> jax.Array:
     """stored (R, C), q (Qt, C), valid (C,) -> dist (Qt, R).
 
     Integer dtypes select the exact quantized-code fast paths (only safe —
     and only requested by ``ops._fused_call`` — when the grid holds
     noise-free integral codes): uint32 operands are bit-packed 1-bit codes
-    (XOR + popcount, ``valid`` already folded in at pack time), int8/int16
-    operands run the distances on narrow integers — on TPU the l2/dot
-    cross term becomes an int8 MXU matmul at a quarter of the f32 HBM
-    bandwidth.  Every int path produces the same f32 values as the float
-    path: all products/sums are exact small integers.
+    (XOR + popcount, ``valid`` already folded in at pack time), int8
+    operands run the distances on narrow integers — the l2/dot cross term
+    becomes an int8 MXU matmul accumulated in int32, at a quarter of the
+    f32 HBM bandwidth.  Every int path produces the same f32 values as the
+    float path: all products/sums are exact small integers.
+
+    The f32 cross term runs at ``Precision.HIGHEST``: the TPU's default
+    single bf16 pass would round 3-bit codes plus device noise to 8
+    mantissa bits, far outside what the l2 norm expansion can absorb.
     """
     if stored.dtype == jnp.uint32 and distance == "hamming":
         return packed_hamming_block(stored, q).astype(jnp.float32)
@@ -347,23 +379,35 @@ def _dist_block_batched(stored, q, valid, distance: str) -> jax.Array:
     if distance in ("l2", "dot"):
         # MXU formulation: fold the column mask into one operand so the
         # cross term is a plain (Qt, C) x (C, R) matmul.
-        qv = q * (valid.astype(q.dtype)[None, :] if integer
-                  else valid[None, :])
-        cross = jax.lax.dot_general(
-            qv, stored, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)         # (Qt, R)
+        if integer:
+            # the mask multiply runs in int32: TPUs have no int8 VPU
+            # arithmetic, only the int8 MXU matmul
+            qv = (q.astype(jnp.int32) * valid.astype(jnp.int32)[None, :]
+                  ).astype(q.dtype)
+            cross = jax.lax.dot_general(
+                qv, stored, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.int32).astype(jnp.float32)
+        else:
+            qv = q * valid[None, :]
+            cross = jax.lax.dot_general(
+                qv, stored, (((1,), (1,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)     # (Qt, R)
         if distance == "dot":
             return -cross
         if integer:
             sf = stored.astype(jnp.float32)
             qf = q.astype(jnp.float32)
-            sn = jnp.sum(sf * sf * valid[None, :], axis=-1)
+            sn = _row_sq_norms(sf, valid)
             qn = jnp.sum(qf * qf * valid[None, :], axis=-1)
         else:
-            sn = jnp.sum(stored * stored * valid[None, :], axis=-1)  # (R,)
+            sn = _row_sq_norms(stored, valid)                        # (R,)
             qn = jnp.sum(q * qv, axis=-1)                            # (Qt,)
         return sn[None, :] - 2.0 * cross + qn[:, None]
-    # VPU broadcast path: (Qt, R, C) block in registers.
+    # VPU broadcast path: (Qt, R, C) block in registers (narrow ints
+    # widen to int32 first, for the same reason as above).
+    if integer:
+        stored, q = stored.astype(jnp.int32), q.astype(jnp.int32)
     s = stored[None, :, :]
     qq = q[:, None, :]
     if distance == "hamming":
@@ -373,30 +417,6 @@ def _dist_block_batched(stored, q, valid, distance: str) -> jax.Array:
     else:
         raise ValueError(distance)
     return jnp.sum(d * valid[None, None, :], axis=-1)
-
-
-def _batched_kernel(stored_ref, query_ref, valid_ref, out_ref, *,
-                    distance: str):
-    stored = stored_ref[0, 0]            # (R, C)
-    q = query_ref[:, 0, :]               # (Qt, C)
-    valid = valid_ref[0]                 # (C,)
-    out_ref[:, 0, 0, :] = _dist_block_batched(stored, q, valid, distance)
-
-
-def _block_batched_kernel(stored_ref, query_ref, valid_ref, out_ref, *,
-                          distance: str):
-    """Bank-blocked variant of ``_batched_kernel``: stored (vb, nh, R, C)
-    resident across the inner Q-tile axis, q (qt, nh, C), valid (nh, C),
-    out (qt, vb, nh, R).  Same tile function vmapped over (nh, vb)."""
-    stored = stored_ref[...]
-    q = query_ref[...]
-    valid = valid_ref[...]
-    per_seg = jax.vmap(
-        lambda s, qseg, v: _dist_block_batched(s, qseg, v, distance),
-        in_axes=(0, 1, 0), out_axes=1)                    # over nh
-    per_bank = jax.vmap(lambda s: per_seg(s, q, valid),
-                        in_axes=0, out_axes=1)            # over vb
-    out_ref[...] = per_bank(stored)
 
 
 @functools.partial(jax.jit,
@@ -411,66 +431,18 @@ def cam_search_batched_pallas(stored: jax.Array, queries: jax.Array,
     """stored (nv, nh, R, C), queries (Q, nh, C), col_valid (nh, C)
     -> dist (Q, nv, nh, R).
 
-    The stored grid is streamed from HBM once for the whole query batch
-    (Q-tile axis innermost; see module docstring for the block layout).
-    ``pipeline=True`` upgrades that to the bank-blocked double-buffered
-    schedule when ``resident_banks`` finds a block size: grid
-    (nv/vb, Q/Qt), each stored byte crosses HBM once per batch instead of
-    once per Q-tile, and ``q_tile=None`` is chosen per geometry by
-    ``choose_q_tile``.  ``pipeline=False`` keeps the historical per-tile
-    grid with ``default_q_tile`` (bit- and schedule-identical off-switch).
+    The distance output of the fused kernel (``_fused_driver``) with no
+    padding rows; its match output is discarded.  Same grids, schedule and
+    ``pipeline`` / ``q_tile`` knobs as ``cam_search_fused_pallas``.
     """
-    nv, nh, R, C = stored.shape
-    Q = queries.shape[0]
-    assert queries.shape == (Q, nh, C), (queries.shape, (Q, nh, C))
-    cdt = _content_dtype((stored,))
-    vb = (resident_banks(nv, nh, R, C, 1, itemsize=cdt.itemsize)
-          if pipeline else 0)
-    if q_tile is None:
-        if pipeline:
-            bcast = 0 if distance in ("l2", "dot") else C
-            q_tile = choose_q_tile(R, C, 1, banks=nv, segs=nh,
-                                   want_dist=False, itemsize=cdt.itemsize,
-                                   bcast_cols=bcast)
-        else:
-            q_tile = default_q_tile(R, C)
-    qt = max(1, min(q_tile, Q))
-    pad = (-Q) % qt
-    if pad:
-        queries = jnp.pad(queries, ((0, pad), (0, 0), (0, 0)))
-    nq = (Q + pad) // qt
-    operands = (stored.astype(cdt), queries.astype(cdt),
-                col_valid.astype(jnp.float32))
-    out_shape = jax.ShapeDtypeStruct((Q + pad, nv, nh, R), jnp.float32)
-    if vb:
-        out = pl.pallas_call(
-            functools.partial(_block_batched_kernel, distance=distance),
-            grid=(nv // vb, nq),
-            in_specs=[
-                pl.BlockSpec((vb, nh, R, C), lambda b, k: (b, 0, 0, 0)),
-                pl.BlockSpec((qt, nh, C), lambda b, k: (k, 0, 0)),
-                pl.BlockSpec((nh, C), lambda b, k: (0, 0)),
-            ],
-            out_specs=pl.BlockSpec((qt, vb, nh, R),
-                                   lambda b, k: (k, b, 0, 0)),
-            out_shape=out_shape,
-            interpret=interpret,
-        )(*operands)
-    else:
-        out = pl.pallas_call(
-            functools.partial(_batched_kernel, distance=distance),
-            grid=(nv, nh, nq),
-            in_specs=[
-                pl.BlockSpec((1, 1, R, C), lambda i, j, k: (i, j, 0, 0)),
-                pl.BlockSpec((qt, 1, C), lambda i, j, k: (k, j, 0)),
-                pl.BlockSpec((1, C), lambda i, j, k: (j, 0)),
-            ],
-            out_specs=pl.BlockSpec((qt, 1, 1, R),
-                                   lambda i, j, k: (k, i, j, 0)),
-            out_shape=out_shape,
-            interpret=interpret,
-        )(*operands)
-    return out[:Q]
+    nv, R = stored.shape[0], stored.shape[2]
+    dist, _ = _fused_driver((stored,), queries, col_valid,
+                            jnp.ones((nv, R), jnp.float32),
+                            distance=distance, sensing="exact",
+                            sensing_limit=0.0, threshold=0.0, q_tile=q_tile,
+                            want_dist=True, interpret=interpret,
+                            pipeline=pipeline)
+    return dist
 
 
 # ---------------------------------------------------------------------------
@@ -492,33 +464,11 @@ def _sense_block(d: jax.Array, rv: jax.Array, sensing: str,
     return m.astype(jnp.float32) * rv[None, :]
 
 
-def _fused_epilogue(d, rv, out_refs, *, sensing: str, sensing_limit: float,
-                    threshold: float, want_dist: bool):
-    """Shared kernel epilogue: padding-row inf mask, sense, write-back."""
-    d = jnp.where(rv[None, :] > 0, d, _INF)   # padding rows never win
-    m = _sense_block(d, rv, sensing, sensing_limit, threshold)
-    if want_dist:
-        out_refs[0][:, 0, 0, :] = d
-        out_refs[1][:, 0, 0, :] = m
-    else:
-        out_refs[0][:, 0, 0, :] = m
-
-
-def _fused_kernel(stored_ref, query_ref, valid_ref, rowv_ref, *out_refs,
-                  distance: str, sensing: str, sensing_limit: float,
-                  threshold: float, want_dist: bool):
-    d = _dist_block_batched(stored_ref[0, 0], query_ref[:, 0, :],
-                            valid_ref[0], distance)
-    _fused_epilogue(d, rowv_ref[0], out_refs, sensing=sensing,
-                    sensing_limit=sensing_limit, threshold=threshold,
-                    want_dist=want_dist)
-
-
 def _tile_fused(tile_planes, qseg, valid, rowv, *, distance: str,
                 sensing: str, sensing_limit: float, threshold: float):
     """One (R, C) tile end-to-end: distance, padding-row inf mask, sense.
-    Shared verbatim by the bank-blocked kernel body and the jnp reference
-    twin — the bit-identity of the pipelined path is by construction."""
+    Shared verbatim by the kernel body and the jnp reference twin — the
+    bit-identity of the kernel path is by construction."""
     if distance == "range":
         d = _range_block_batched(tile_planes[0], tile_planes[1], qseg, valid)
     else:
@@ -528,37 +478,35 @@ def _tile_fused(tile_planes, qseg, valid, rowv, *, distance: str,
     return d, m
 
 
-def _block_fused_kernel(*refs, n_planes: int, distance: str, sensing: str,
-                        sensing_limit: float, threshold: float,
-                        want_dist: bool):
-    """Bank-blocked pipelined kernel body.
+def _fused_kernel(*refs, n_planes: int, distance: str, sensing: str,
+                  sensing_limit: float, threshold: float, want_dist: bool):
+    """Fused kernel body, shared by both grids.
 
-    Per grid step (b, k) the refs hold a whole (vb, nh, R, C) bank block
-    per stored plane (resident across the inner Q-tile axis; Pallas
-    double-buffers the NEXT block's HBM fetch while this one computes), a
-    (qt, nh, C) query tile, (nh, C) col_valid, (vb, R) row_valid, and
-    (qt, vb, nh, R) out tiles.  The body vmaps the same per-tile function
-    as ``cam_fused_reference`` over (nh, vb)."""
+    Per grid step the refs hold a (vb, nh, R, C) bank block per stored
+    plane, the (nh, qt, C) query tile, (nh, 1, C) col_valid, (vb, 1, R)
+    row_valid and (vb, nh, qt, R) out tiles (the per-tile grid passes
+    vb = nh = 1).  A ``fori_loop`` walks the block's vb·nh tiles one at a
+    time, so neither compile time nor the live temporaries grow with the
+    block size; each tile runs the same ``_tile_fused`` as the jnp twin."""
     plane_refs = refs[:n_planes]
     query_ref, valid_ref, rowv_ref = refs[n_planes:n_planes + 3]
     out_refs = refs[n_planes + 3:]
-    planes = tuple(r[...] for r in plane_refs)            # (vb, nh, R, C)
-    q = query_ref[...]                                    # (qt, nh, C)
-    cv = valid_ref[...]                                   # (nh, C)
-    rv = rowv_ref[...]                                    # (vb, R)
-    tile = functools.partial(_tile_fused, distance=distance, sensing=sensing,
-                             sensing_limit=sensing_limit, threshold=threshold)
-    per_seg = jax.vmap(tile, in_axes=((0,) * n_planes, 1, 0, None),
-                       out_axes=(1, 1))                   # over nh
-    per_bank = jax.vmap(lambda tp, rowv: per_seg(tp, q, cv, rowv),
-                        in_axes=((0,) * n_planes, 0),
-                        out_axes=(1, 1))                  # over vb
-    d, m = per_bank(planes, rv)                           # (qt, vb, nh, R)
-    if want_dist:
-        out_refs[0][...] = d
-        out_refs[1][...] = m
-    else:
-        out_refs[0][...] = m
+    vb, nh = plane_refs[0].shape[:2]
+
+    def tile(t, carry):
+        v, j = t // nh, t % nh
+        d, m = _tile_fused(tuple(r[v, j] for r in plane_refs), query_ref[j],
+                           valid_ref[j][0], rowv_ref[v][0],
+                           distance=distance, sensing=sensing,
+                           sensing_limit=sensing_limit, threshold=threshold)
+        if want_dist:
+            out_refs[0][v, j] = d
+            out_refs[1][v, j] = m
+        else:
+            out_refs[0][v, j] = m
+        return carry
+
+    jax.lax.fori_loop(0, vb * nh, tile, 0)
 
 
 def _content_dtype(stored_planes):
@@ -587,88 +535,109 @@ def _fused_driver(stored_planes, queries: jax.Array,
     block b+1 while block b computes, and ``vb == nv`` is the VMEM-resident
     fast path (whole store on-chip, grid (1, Q/Qt)).  ``q_tile=None`` is
     chosen per geometry by the measured-model ``choose_q_tile``.
+    ``pipeline=False`` (and a bank too large for the budget) runs the
+    (nv, nh, Q/Qt) per-tile grid with ``default_q_tile``.  Both grids run
+    the same kernel body, so they compute identical tile math.
 
-    ``pipeline=False`` is the bit- and schedule-identical off-switch: the
-    historical (nv, nh, Q/Qt) per-tile grid with ``default_q_tile``.
-    Both paths compute identical tile math — the block body vmaps the same
-    tile functions the per-tile bodies call."""
+    Block layout: queries enter as (nh, Q, C), col_valid as (nh, 1, C),
+    row_valid as (nv, 1, R), and the kernel writes bank-major
+    (nv, nh, Q, R) outputs, so the last two dims of every block are a full
+    array dim or an (8, 128)-aligned tile — Mosaic's tiling rule — and no
+    block carries a size-1 sublane dim that the (8, 128) tiling would pad
+    8x.  The result is transposed back to (Q, nv, nh, R) outside the
+    kernel.  A Q-tile below the 8-row sublane tile rounds up to 8 (on
+    both paths, so interpret mode runs the compiled schedule); the compiled
+    path passes the VMEM count (``fused_vmem_bytes``) as Mosaic's
+    ``vmem_limit_bytes``, and sizes blocks with the device's entry of
+    ``DEVICE_MODELS``."""
+    from jax.experimental.pallas import tpu as pltpu
+
     nv, nh, R, C = stored_planes[0].shape
     Q = queries.shape[0]
     n_planes = len(stored_planes)
     assert queries.shape == (Q, nh, C), (queries.shape, (Q, nh, C))
     assert row_valid.shape == (nv, R), (row_valid.shape, (nv, R))
     cdt = _content_dtype(stored_planes)
-    vb = (resident_banks(nv, nh, R, C, n_planes, itemsize=cdt.itemsize)
+    model = device_model()
+    budget = model["vmem_budget_bytes"]
+    vb = (resident_banks(nv, nh, R, C, n_planes, itemsize=cdt.itemsize,
+                         budget_bytes=budget)
           if pipeline else 0)
+    # l2/dot take the MXU matmul form; everything else broadcasts a
+    # (Qt, R, C) compare block on the VPU (for packed hamming C is
+    # already the packed word width, so the cap never binds)
+    bcast = 0 if distance in ("l2", "dot") else C
     if q_tile is None:
-        if pipeline:
-            # l2/dot take the MXU matmul form; everything else broadcasts a
-            # (Qt, rows, C) compare block on the VPU (for packed hamming C
-            # is already the packed word width, so the cap never binds)
-            bcast = 0 if distance in ("l2", "dot") else C
+        if vb:
             q_tile = choose_q_tile(R, C, n_planes, banks=nv, segs=nh,
                                    want_dist=want_dist,
-                                   itemsize=cdt.itemsize, bcast_cols=bcast)
+                                   itemsize=cdt.itemsize, bcast_cols=bcast,
+                                   budget_bytes=budget,
+                                   hbm_bytes_per_s=model["hbm_bytes_per_s"])
         else:
             q_tile = default_q_tile(R, C, n_planes)
     qt = max(1, min(q_tile, Q))
+    if qt < Q and qt % 8:           # Mosaic's 8-row sublane tile
+        qt = min(8, Q)
     pad = (-Q) % qt
     if pad:
         queries = jnp.pad(queries, ((0, pad), (0, 0), (0, 0)))
     nq = (Q + pad) // qt
-    shape = jax.ShapeDtypeStruct((Q + pad, nv, nh, R), jnp.float32)
+    shape = jax.ShapeDtypeStruct((nv, nh, Q + pad, R), jnp.float32)
     planes = tuple(p.astype(cdt) for p in stored_planes)
-    qs = queries.astype(cdt)
-    cv = col_valid.astype(jnp.float32)
-    rv = row_valid.astype(jnp.float32)
+    qs = jnp.transpose(queries.astype(cdt), (1, 0, 2))        # (nh, Qp, C)
+    cv = col_valid.astype(jnp.float32)[:, None, :]            # (nh, 1, C)
+    rv = row_valid.astype(jnp.float32)[:, None, :]            # (nv, 1, R)
+    body = functools.partial(
+        _fused_kernel, n_planes=n_planes, distance=distance,
+        sensing=sensing, sensing_limit=sensing_limit, threshold=threshold,
+        want_dist=want_dist)
+    out_planes = 2 if want_dist else 1
+
+    def need(v):
+        return fused_vmem_bytes(v, qt, nh if vb else 1, R, C, n_planes,
+                                itemsize=cdt.itemsize,
+                                out_planes=out_planes, bcast_cols=bcast)
+
+    # an explicit q_tile can outgrow the block: shrink vb until it fits
+    while vb > 1 and need(vb) > budget:
+        vb = max(v for v in range(1, vb) if nv % v == 0)
     if vb:
-        body = functools.partial(
-            _block_fused_kernel, n_planes=n_planes, distance=distance,
-            sensing=sensing, sensing_limit=sensing_limit,
-            threshold=threshold, want_dist=want_dist)
-        spec = pl.BlockSpec((qt, vb, nh, R), lambda b, k: (k, b, 0, 0))
+        grid = (nv // vb, nq)
         stored_spec = pl.BlockSpec((vb, nh, R, C), lambda b, k: (b, 0, 0, 0))
-        out = pl.pallas_call(
-            body,
-            grid=(nv // vb, nq),
-            in_specs=[stored_spec] * n_planes + [
-                pl.BlockSpec((qt, nh, C), lambda b, k: (k, 0, 0)),
-                pl.BlockSpec((nh, C), lambda b, k: (0, 0)),
-                pl.BlockSpec((vb, R), lambda b, k: (b, 0)),
-            ],
-            out_specs=(spec, spec) if want_dist else spec,
-            out_shape=(shape, shape) if want_dist else shape,
-            interpret=interpret,
-        )(*planes, qs, cv, rv)
+        in_specs = [stored_spec] * n_planes + [
+            pl.BlockSpec((nh, qt, C), lambda b, k: (0, k, 0)),
+            pl.BlockSpec((nh, 1, C), lambda b, k: (0, 0, 0)),
+            pl.BlockSpec((vb, 1, R), lambda b, k: (b, 0, 0)),
+        ]
+        spec = pl.BlockSpec((vb, nh, qt, R), lambda b, k: (b, 0, k, 0))
     else:
-        if distance == "range":
-            body = functools.partial(
-                _range_fused_kernel, sensing=sensing,
-                sensing_limit=sensing_limit, threshold=threshold,
-                want_dist=want_dist)
-        else:
-            body = functools.partial(
-                _fused_kernel, distance=distance, sensing=sensing,
-                sensing_limit=sensing_limit, threshold=threshold,
-                want_dist=want_dist)
-        spec = pl.BlockSpec((qt, 1, 1, R), lambda i, j, k: (k, i, j, 0))
+        grid = (nv, nh, nq)
         stored_spec = pl.BlockSpec((1, 1, R, C),
                                    lambda i, j, k: (i, j, 0, 0))
-        out = pl.pallas_call(
-            body,
-            grid=(nv, nh, nq),
-            in_specs=[stored_spec] * n_planes + [
-                pl.BlockSpec((qt, 1, C), lambda i, j, k: (k, j, 0)),
-                pl.BlockSpec((1, C), lambda i, j, k: (j, 0)),
-                pl.BlockSpec((1, R), lambda i, j, k: (i, 0)),
-            ],
-            out_specs=(spec, spec) if want_dist else spec,
-            out_shape=(shape, shape) if want_dist else shape,
-            interpret=interpret,
-        )(*planes, qs, cv, rv)
+        in_specs = [stored_spec] * n_planes + [
+            pl.BlockSpec((1, qt, C), lambda i, j, k: (j, k, 0)),
+            pl.BlockSpec((1, 1, C), lambda i, j, k: (j, 0, 0)),
+            pl.BlockSpec((1, 1, R), lambda i, j, k: (i, 0, 0)),
+        ]
+        spec = pl.BlockSpec((1, 1, qt, R), lambda i, j, k: (i, j, k, 0))
+    out = pl.pallas_call(
+        body,
+        grid=grid,
+        in_specs=in_specs,
+        out_specs=(spec, spec) if want_dist else spec,
+        out_shape=(shape, shape) if want_dist else shape,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=need(max(vb, 1)) + (4 << 20)),
+        interpret=interpret,
+    )(*planes, qs, cv, rv)
+
+    def back(o):                        # (nv, nh, Qp, R) -> (Q, nv, nh, R)
+        return jnp.transpose(o, (2, 0, 1, 3))[:Q]
+
     if want_dist:
-        return out[0][:Q], out[1][:Q]
-    return out[:Q]
+        return back(out[0]), back(out[1])
+    return back(out)
 
 
 @functools.partial(jax.jit,
@@ -695,8 +664,8 @@ def cam_search_fused_pallas(stored: jax.Array, queries: jax.Array,
     rows are +inf, matching ``core.subarray.subarray_query``.
 
     ``pipeline=True`` selects the bank-blocked double-buffered schedule
-    (see ``_fused_driver``); ``pipeline=False`` is the bit- and
-    schedule-identical historical per-tile grid.
+    (see ``_fused_driver``); ``pipeline=False`` runs the same kernel body
+    on the per-tile grid, with bit-identical results.
     """
     return _fused_driver((stored,), queries, col_valid, row_valid,
                          distance=distance, sensing=sensing,
@@ -719,16 +688,6 @@ def _range_block_batched(lo, hi, q, valid) -> jax.Array:
     viol = ((qq < lo[None, :, :]) | (qq > hi[None, :, :])
             ).astype(jnp.float32)
     return jnp.sum(viol * valid[None, None, :], axis=-1)
-
-
-def _range_fused_kernel(lo_ref, hi_ref, query_ref, valid_ref, rowv_ref,
-                        *out_refs, sensing: str, sensing_limit: float,
-                        threshold: float, want_dist: bool):
-    d = _range_block_batched(lo_ref[0, 0], hi_ref[0, 0], query_ref[:, 0, :],
-                             valid_ref[0])
-    _fused_epilogue(d, rowv_ref[0], out_refs, sensing=sensing,
-                    sensing_limit=sensing_limit, threshold=threshold,
-                    want_dist=want_dist)
 
 
 @functools.partial(jax.jit,
@@ -755,8 +714,8 @@ def cam_range_fused_pallas(stored_lo: jax.Array, stored_hi: jax.Array,
     each (Q, nv, nh, R) — dist is the range-violation count, +inf on
     padding rows — or ``match`` alone when ``want_dist=False`` (the count
     tensor then never hits HBM; the ACAM exact-match AND-merge path).
-    The grid is (nv, nh, Q/Qt) with the Q-tile innermost, so both stored
-    planes are streamed from HBM once per query batch.
+    Both stored planes stream from HBM once per query batch, on the same
+    grids as the point kernel (``_fused_driver``).
     """
     assert stored_hi.shape == stored_lo.shape, (stored_hi.shape,
                                                 stored_lo.shape)
@@ -780,10 +739,9 @@ def cam_fused_reference(stored_planes, queries: jax.Array,
                         sensing_limit: float = 0.0, threshold: float = 0.0,
                         want_dist: bool = True):
     """Pure-jnp twin of ``cam_search_fused_pallas`` / ``cam_range_fused_
-    pallas``, built from the SAME per-tile functions the kernel bodies call
-    (``_dist_block_batched`` / ``_range_block_batched`` / ``_sense_block``)
-    vmapped over the (nv, nh) grid — so its results are the kernels', by
-    construction.  ``ops._fused_call`` dispatches here for interpret-mode
+    pallas``, built from the SAME per-tile function the kernel body calls
+    (``_tile_fused``) and walking the (nv, nh) tiles one at a time as the
+    body does — so its results are the kernels', by construction.  ``ops._fused_call`` dispatches here for interpret-mode
     batches below ``SMALL_Q_CROSSOVER``, where per-grid-step emulation
     overhead dominates (BENCH: kernel_acam_range_q1 ran at 0.18x of jnp).
 
@@ -792,17 +750,24 @@ def cam_fused_reference(stored_planes, queries: jax.Array,
     """
     cdt = _content_dtype(stored_planes)
     planes = tuple(p.astype(cdt) for p in stored_planes)
-    n_planes = len(planes)
-    q = queries.astype(cdt)
+    # pad the batch to the kernel's 8-row sublane tile, so each tile's
+    # cross-term matmul has the kernel's shape (and rounding)
+    Q = queries.shape[0]
+    q = jnp.pad(queries.astype(cdt), ((0, (-Q) % 8), (0, 0), (0, 0)))
     cv = col_valid.astype(jnp.float32)
     rv = row_valid.astype(jnp.float32)
-    tile = functools.partial(_tile_fused, distance=distance, sensing=sensing,
-                             sensing_limit=float(sensing_limit),
-                             threshold=float(threshold))
-    per_seg = jax.vmap(tile, in_axes=((0,) * n_planes, 1, 0, None),
-                       out_axes=(1, 1))                  # over nh
-    per_bank = jax.vmap(lambda tp, rowv: per_seg(tp, q, cv, rowv),
-                        in_axes=((0,) * n_planes, 0),
-                        out_axes=(1, 1))                 # over nv
-    d, m = per_bank(planes, rv)                          # (Q, nv, nh, R)
-    return (d, m) if want_dist else m
+    nv, nh, R = planes[0].shape[:3]
+
+    def tile(t):                 # the kernel body's walk, one tile a step
+        v, j = t // nh, t % nh
+        return _tile_fused(tuple(p[v, j] for p in planes), q[:, j], cv[j],
+                           rv[v], distance=distance, sensing=sensing,
+                           sensing_limit=float(sensing_limit),
+                           threshold=float(threshold))
+
+    d, m = jax.lax.map(tile, jnp.arange(nv * nh))        # (nv*nh, Q, R)
+
+    def back(o):                 # -> (Q, nv, nh, R)
+        return jnp.transpose(o.reshape(nv, nh, -1, R), (2, 0, 1, 3))[:Q]
+
+    return (back(d), back(m)) if want_dist else back(m)

@@ -1,8 +1,11 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-Each op auto-selects interpret mode off-TPU (this container is CPU-only; on
-a real TPU slice the same call sites compile the Mosaic kernels) and pads
-inputs to kernel-friendly shapes.  Query batches dispatch to the
+Each op compiles its Mosaic kernel on a TPU backend and runs it in Pallas
+interpret mode on the CPU backend (how the test suite runs); any other
+backend raises.  ``tests/test_tpu_compile.py`` compiles the fused search
+kernels (``cam_search_fused*``, whose driver ``cam_search`` shares) and
+the packed-hamming prefilter kernel for a described TPU v5e.  The ops
+pad inputs to kernel-friendly shapes.  Query batches dispatch to the
 query-batched kernels (one HBM pass over the stored grid per batch);
 ``cam_search_vmap`` keeps the old per-query vmap path as a baseline.
 """
@@ -24,7 +27,15 @@ from .hamming_pack import hamming_packed_batched_pallas, hamming_packed_pallas
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Compiled Mosaic on TPU, interpret mode on CPU, nothing else."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"the Pallas kernels run compiled on TPU or interpreted on CPU; "
+        f"backend {backend!r} has neither (set sim.use_kernel=False)")
 
 
 # --------------------------------------------------------------------------
@@ -85,8 +96,9 @@ def _int_cast(stored: jax.Array, queries: jax.Array, col_valid: jax.Array,
     by the caller to describe a grid of exact small integers (no device
     noise).  1-bit hamming codes bit-pack into uint32 words with
     ``col_valid`` folded in as the care mask (both operands masked, so XOR
-    contributes 0 on don't-care columns); wider codes cast to int8 (≤7
-    bits) or int16 (8 bits).  Returns the (possibly transformed)
+    contributes 0 on don't-care columns); wider codes up to 7 bits cast
+    to int8 (8-bit codes overflow int8 and keep the f32 path).  Returns
+    the (possibly transformed)
     ``(stored, queries, col_valid)`` triple — unchanged when no fast path
     applies.  Every path is bit-exact vs f32: the distances are sums of
     exact small-integer products.
@@ -100,9 +112,8 @@ def _int_cast(stored: jax.Array, queries: jax.Array, col_valid: jax.Array,
         sp = pack_bits(stored, col_valid[None, :, None, :])
         qp = pack_bits(queries, col_valid[None])
         return sp, qp, jnp.ones((nh, sp.shape[-1]), jnp.float32)
-    if distance in ("hamming", "l1", "l2", "dot") and int_codes <= 8:
-        idt = jnp.int8 if int_codes <= 7 else jnp.int16
-        return stored.astype(idt), queries.astype(idt), col_valid
+    if distance in ("hamming", "l1", "l2", "dot") and int_codes <= 7:
+        return stored.astype(jnp.int8), queries.astype(jnp.int8), col_valid
     return stored, queries, col_valid
 
 
@@ -218,8 +229,6 @@ def cam_search_fused_sharded(stored: jax.Array, queries: jax.Array, *,
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.launch.mesh import compat_shard_map
-
     nv, nh, R, C = stored.shape[:4]
     n_banks = dict(zip(mesh.axis_names, mesh.axis_sizes))[bank_axis]
     if nv % n_banks:
@@ -239,10 +248,11 @@ def cam_search_fused_sharded(stored: jax.Array, queries: jax.Array, *,
             pipeline=pipeline, int_codes=int_codes)
 
     out_spec = P(None, bank_axis)
-    return compat_shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(bank_axis), P(bank_axis), P(), P()),
-        out_specs=(out_spec, out_spec) if want_dist else out_spec)(
+        out_specs=(out_spec, out_spec) if want_dist else out_spec,
+        check_vma=False)(
         stored, row_valid, col_valid, queries)
 
 

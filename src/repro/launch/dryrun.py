@@ -119,8 +119,6 @@ def lower_kind(cfg: ModelConfig, kind: str, batch: int, seq: int, mesh,
 # ---------------------------------------------------------------------------
 def _extract_costs(compiled, chips: int) -> Dict[str, float]:
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):   # jax <= 0.4.x: one dict per device
-        cost = cost[0] if cost else {}
     coll = parse_collectives(compiled.as_text(), chips)
     return {
         "flops": float(cost.get("flops", 0.0)),

@@ -7,59 +7,22 @@ touches jax device state; the dry-run sets XLA_FLAGS for 512 host devices
 from __future__ import annotations
 
 import jax
-
-
-def compat_make_mesh(shape, axes):
-    """``jax.make_mesh`` across jax versions.
-
-    Newer jax wants explicit ``axis_types`` (Auto); jax <= 0.4.x has neither
-    the kwarg nor ``jax.sharding.AxisType``.  All repo call sites go through
-    here so the version probe lives in one place.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
-
-
-def compat_shard_map(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions.
-
-    jax <= 0.4.x only ships ``jax.experimental.shard_map``; there we disable
-    ``check_rep`` (its replication checker predates several collectives we
-    use and rejects valid programs the stable API accepts).
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as esm
-    return esm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
-def compat_abstract_mesh(shape, axes):
-    """``jax.sharding.AbstractMesh`` across jax versions: newer jax takes
-    (sizes, names); jax <= 0.4.x takes ((name, size), ...) pairs."""
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(shape, axes)
-    except TypeError:
-        return AbstractMesh(tuple(zip(axes, shape)))
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single-pod (256 chips) or 2x16x16 multi-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh(model: int = 1):
     """Whatever this host has (used by smoke tests / examples)."""
     n = len(jax.devices())
     data = max(1, n // model)
-    return compat_make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def make_cam_mesh(banks: int | None = None, queries: int = 1):
@@ -73,4 +36,5 @@ def make_cam_mesh(banks: int | None = None, queries: int = 1):
     n = len(jax.devices())
     if banks is None:
         banks = max(1, n // max(1, queries))
-    return compat_make_mesh((banks, queries), ("bank", "query"))
+    return jax.make_mesh((banks, queries), ("bank", "query"),
+                         axis_types=(AxisType.Auto,) * 2)

@@ -22,7 +22,6 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.core.quantize import linear_quantize
-from repro.launch.mesh import compat_shard_map
 
 NEG_INF = -1e30
 
@@ -180,7 +179,7 @@ def cam_decode_attention_hierarchical(q: jax.Array, k_cache: jax.Array,
         out = num / jnp.maximum(den, 1e-30)
         return out.reshape(-1, H, Dv).astype(qb.dtype)
 
-    return compat_shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(b_spec, Psp(b_spec[0] if dp_ok else None, "model"),
                   Psp(b_spec[0] if dp_ok else None, "model"), b_spec),

@@ -27,7 +27,6 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.core.quantize import linear_quantize
-from repro.launch.mesh import compat_shard_map
 from repro.runtime import sharding as sh
 
 from .layers import P, mlp, mlp_spec
@@ -272,7 +271,7 @@ def moe_block(params, cfg: ModelConfig, x: jax.Array,
                               * cfg.moe_top_k / n_model) + 1)
         body = _moe_a2a_body(cfg, n_model, capacity)
         Pall = Psp(dp + ("model",))
-        yf = compat_shard_map(
+        yf = jax.shard_map(
             body, mesh=mesh,
             in_specs=(Pall, Psp(), Psp("model"), Psp("model"),
                       Psp("model"), Psp(), Psp(), Psp()),
@@ -298,7 +297,7 @@ def moe_block(params, cfg: ModelConfig, x: jax.Array,
             y = y + mlp({"wi_gate": sg, "wi_up": su, "wo": so}, xl)
             return jax.lax.psum(y, "model")
 
-        yf = compat_shard_map(
+        yf = jax.shard_map(
             body, mesh=mesh,
             in_specs=(x_spec, Psp(), Psp("model"), Psp("model"),
                       Psp("model"), Psp(None, "model"), Psp(None, "model"),
@@ -317,7 +316,7 @@ def moe_block(params, cfg: ModelConfig, x: jax.Array,
         y = y + mlp({"wi_gate": sg, "wi_up": su, "wo": so}, xl)
         return jax.lax.psum(y, "model")
 
-    yf = compat_shard_map(
+    yf = jax.shard_map(
         body, mesh=mesh,
         in_specs=(x_spec, Psp(), Psp(None, None, "model"),
                   Psp(None, None, "model"), Psp(None, "model"),

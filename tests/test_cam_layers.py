@@ -6,7 +6,6 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
-from repro.launch.mesh import compat_make_mesh
 from repro.models.attention import decode_attention
 from repro.models.cam_attention import (cam_decode_attention,
                                         cam_decode_attention_pallas,
@@ -134,7 +133,8 @@ def test_moe_ep_mode_matches_tp_single_device():
     cfg = get_config("deepseek-moe-16b").reduced()
     params = L.init_params(KEY, M.moe_spec(cfg))
     x = jax.random.normal(KEY, (8, cfg.d_model)).astype(jnp.bfloat16)
-    mesh = compat_make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     with sharding_ctx(mesh):
         tp = M.moe_block(params, cfg, x, mode="tp")
         ep = M.moe_block(params, cfg, x, mode="ep")
@@ -180,7 +180,6 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 os.environ["JAX_PLATFORMS"] = "cpu"
 import jax, jax.numpy as jnp
 from repro.configs import get_config
-from repro.launch.mesh import compat_make_mesh
 from repro.models import moe as M
 from repro.models import layers as L
 from repro.runtime import sharding_ctx
@@ -188,7 +187,8 @@ cfg = get_config("deepseek-moe-16b").reduced().replace(moe_capacity_factor=8.0)
 params = L.init_params(jax.random.PRNGKey(0), M.moe_spec(cfg))
 x = (0.5*jax.random.normal(jax.random.PRNGKey(1), (2, 16, cfg.d_model))).astype(jnp.bfloat16)
 ref = M.moe_block(params, cfg, x)
-mesh = compat_make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 with sharding_ctx(mesh):
     a = jax.jit(lambda p, x: M.moe_block(p, cfg, x, mode="a2a"))(params, x)
 err = float(jnp.max(jnp.abs(ref.astype(jnp.float32)-a.astype(jnp.float32))))

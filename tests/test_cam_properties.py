@@ -152,11 +152,11 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 os.environ["JAX_PLATFORMS"] = "cpu"   # host-device trick needs the CPU backend
 import jax, jax.numpy as jnp
 from repro.configs import get_config
-from repro.launch.mesh import compat_make_mesh
 from repro.models.cam_attention import (cam_decode_attention,
                                         cam_decode_attention_hierarchical)
 from repro.runtime import sharding_ctx
-mesh = compat_make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 B, S, H, KVH, D = 4, 64, 6, 2, 16
 cfg = get_config("chameleon-34b").reduced().replace(cam_topk=8)
 k1, k2, k3 = jax.random.split(jax.random.PRNGKey(0), 3)

@@ -22,11 +22,11 @@ os.environ["JAX_PLATFORMS"] = "cpu"   # host-device trick needs the CPU backend
 import json
 import jax
 from repro.launch.dryrun import lower_kind, probe_costs
-from repro.launch.mesh import compat_make_mesh
 from repro.configs import get_config
 from repro.runtime import ShardingRules
 
-mesh = compat_make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 rules = ShardingRules()
 out = {}
 cfg = get_config("qwen2-1.5b").replace(n_layers=2, d_model=256,
@@ -36,9 +36,7 @@ for kind, batch, seq in (("train", 8, 256), ("prefill", 4, 256),
                          ("decode", 8, 256)):
     lowered = lower_kind(cfg, kind, batch, seq, mesh, rules)
     compiled = lowered.compile()
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):   # jax <= 0.4.x: per-device dicts
-        cost = cost[0] if cost else {}
+    cost = compiled.cost_analysis() or {}
     mem = compiled.memory_analysis()
     costs, colls = probe_costs(cfg, kind, batch, seq, mesh, rules, "tp")
     out[kind] = {

@@ -9,7 +9,6 @@ import pytest
 from _hypothesis_compat import given, settings, strategies as st
 
 from repro.checkpoint import committed_steps, restore, save
-from repro.launch.mesh import compat_make_mesh, compat_shard_map
 from repro.configs import get_config
 from repro.data import SyntheticLM
 from repro.optim import (AdamW, constant, dequantize_int8, ef_compress,
@@ -109,7 +108,8 @@ def test_elastic_restore_across_mesh_shapes(tmp_path):
     opt = AdamW(lr=constant(1e-3))
     state = init_state(KEY, cfg, opt)
     save(str(tmp_path), 3, state)
-    mesh = compat_make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     step, restored = elastic.elastic_restore(
         str(tmp_path), state, state_axes(cfg), mesh)
     assert step == 3
@@ -147,7 +147,8 @@ def test_error_feedback_converges():
 
 def test_compressed_psum_shard_map():
     devs = jax.devices()
-    mesh = compat_make_mesh((len(devs),), ("data",))
+    mesh = jax.make_mesh((len(devs),), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 1)
     from jax.sharding import PartitionSpec as P
     from repro.optim import compressed_psum
 
@@ -157,7 +158,7 @@ def test_compressed_psum_shard_map():
     def body(g, e):
         return compressed_psum(g, e, "data")
 
-    out, new_err = jax.jit(compat_shard_map(
+    out, new_err = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P())))(
         grads, errs)
     np.testing.assert_allclose(np.asarray(out["w"]),
@@ -610,8 +611,8 @@ def _mesh_16x16_abstract():
     # AbstractMesh-like resolution check without devices: use a tiny mesh
     # and a fake big one via spec_for's pure math (mesh only provides
     # axis names and sizes, so we use jax.sharding.AbstractMesh).
-    from repro.launch.mesh import compat_abstract_mesh
-    return compat_abstract_mesh((16, 16), ("data", "model"))
+    from jax.sharding import AbstractMesh
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 def test_resolver_divisibility_fallback():
@@ -638,9 +639,9 @@ def test_resolver_no_double_axis_use():
 def test_resolver_cam_rules():
     """cam_bank/cam_query resolve on a CAM mesh and stay silent on the
     LM meshes (no 'bank'/'query' axes there)."""
-    from repro.launch.mesh import compat_abstract_mesh
+    from jax.sharding import AbstractMesh
     rules = ShardingRules()
-    cam_mesh = compat_abstract_mesh((4, 2), ("bank", "query"))
+    cam_mesh = AbstractMesh((4, 2), ("bank", "query"))
     spec = rules.spec_for((8, 2, 16, 16),
                           ("cam_bank", None, "cam_row", "cam_col"),
                           cam_mesh)

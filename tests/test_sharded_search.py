@@ -367,11 +367,13 @@ def test_comparator_merge_associative(seed):
     np.testing.assert_array_equal(np.asarray(fv), np.asarray(tv3))
 
 
-@given(st.integers(0, 10 ** 6), st.integers(2, 4))
+@given(st.integers(0, 10 ** 6), st.sampled_from((2, 4, 8)))
 @settings(max_examples=8, deadline=None)
 def test_comparator_merge_shard_order_permutation_invariant(seed, n_shards):
     """With continuous (tie-free) scores the merged winner set does not
-    depend on the order shards contribute their candidates."""
+    depend on the order shards contribute their candidates.  Shard counts
+    are the divisors of nv, as on a bank mesh (nv is padded to a bank-axis
+    multiple before sharding)."""
     rng = np.random.default_rng(seed)
     nv, R, k = 8, 4, 4
     values = rng.standard_normal((nv, R)).astype(np.float32)
