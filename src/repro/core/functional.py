@@ -740,9 +740,10 @@ class FunctionalSimulator:
     def query_codes(self, state: CAMState, queries: jax.Array) -> jax.Array:
         """Quantize with the store's shared scale: (Q, N) code-domain."""
         cfg = self.config
-        qcodes, _, _ = quantize.quantize_for_cell(
-            queries, cfg.circuit.cell_type, cfg.app.data_bits,
-            state.lo, state.hi)
+        with jax.named_scope("cam.quantize"):
+            qcodes, _, _ = quantize.quantize_for_cell(
+                queries, cfg.circuit.cell_type, cfg.app.data_bits,
+                state.lo, state.hi)
         return qcodes
 
     def segment_queries(self, state: CAMState, queries: jax.Array
@@ -784,15 +785,16 @@ class FunctionalSimulator:
         dist, match = self.search_shard(
             sub_grid, qseg, col_valid=state.col_valid, row_valid=sub_rv,
             key=key, bank_ids=bank_ids)
-        return merge.merge_selected(
-            dist, match, bank_ids, nv_total=spec.nv,
-            match_type=cfg.app.match_type,
-            h_merge=cfg.arch.h_merge,
-            v_merge=cfg.arch.v_merge,
-            match_param=self.match_k(spec.padded_K),
-            sensing_limit=cfg.circuit.sensing_limit,
-            threshold=float(cfg.app.match_param)
-            if cfg.app.match_type == "threshold" else 0.0)
+        with jax.named_scope("cam.merge"):
+            return merge.merge_selected(
+                dist, match, bank_ids, nv_total=spec.nv,
+                match_type=cfg.app.match_type,
+                h_merge=cfg.arch.h_merge,
+                v_merge=cfg.arch.v_merge,
+                match_param=self.match_k(spec.padded_K),
+                sensing_limit=cfg.circuit.sensing_limit,
+                threshold=float(cfg.app.match_param)
+                if cfg.app.match_type == "threshold" else 0.0)
 
     def _to_original(self, state: CAMState, idx, mask):
         """Map placed-order results back to the caller's row order.
@@ -801,9 +803,10 @@ class FunctionalSimulator:
         gather and the placed mask scatters onto original positions."""
         if state.perm is None:
             return idx, mask
-        safe = jnp.take(state.perm, jnp.maximum(idx, 0))
-        idx = jnp.where(idx >= 0, safe, -1)
-        mask = jnp.zeros_like(mask).at[..., state.perm].set(mask)
+        with jax.named_scope("cam.backmap"):
+            safe = jnp.take(state.perm, jnp.maximum(idx, 0))
+            idx = jnp.where(idx >= 0, safe, -1)
+            mask = jnp.zeros_like(mask).at[..., state.perm].set(mask)
         return idx, mask
 
     def search_shard(self, grid: jax.Array, qseg: jax.Array, *,
@@ -832,20 +835,21 @@ class FunctionalSimulator:
         bits = cfg.app.data_bits
 
         def run(g, q):
-            return subarray.subarray_query_batched(
-                g, q,
-                distance=cfg.app.distance,
-                sensing=cfg.circuit.sensing,
-                sensing_limit=cfg.circuit.sensing_limit,
-                threshold=float(cfg.app.match_param)
-                if cfg.app.match_type == "threshold" else 0.0,
-                col_valid=col_valid,
-                row_valid=row_valid,
-                use_kernel=self.use_kernel,
-                want_dist=self.need_dist(),
-                q_tile=self.q_tile,
-                pipeline=self.pipeline,
-                int_codes=self.int_codes)
+            with jax.named_scope("cam.search"):
+                return subarray.subarray_query_batched(
+                    g, q,
+                    distance=cfg.app.distance,
+                    sensing=cfg.circuit.sensing,
+                    sensing_limit=cfg.circuit.sensing_limit,
+                    threshold=float(cfg.app.match_param)
+                    if cfg.app.match_type == "threshold" else 0.0,
+                    col_valid=col_valid,
+                    row_valid=row_valid,
+                    use_kernel=self.use_kernel,
+                    want_dist=self.need_dist(),
+                    q_tile=self.q_tile,
+                    pipeline=self.pipeline,
+                    int_codes=self.int_codes)
 
         if cfg.device.variation not in ("c2c", "both"):
             return run(grid, qseg)
@@ -870,31 +874,33 @@ class FunctionalSimulator:
     def merge_rows(self, dist, match, padded_K: int):
         """Single-device merge of (Q, nv, nh, R) subarray outputs."""
         cfg = self.config
-        return merge.merge(
-            dist, match,
-            match_type=cfg.app.match_type,
-            h_merge=cfg.arch.h_merge,
-            v_merge=cfg.arch.v_merge,
-            match_param=self.match_k(padded_K),
-            sensing_limit=cfg.circuit.sensing_limit,
-            threshold=float(cfg.app.match_param)
-            if cfg.app.match_type == "threshold" else 0.0)
+        with jax.named_scope("cam.merge"):
+            return merge.merge(
+                dist, match,
+                match_type=cfg.app.match_type,
+                h_merge=cfg.arch.h_merge,
+                v_merge=cfg.arch.v_merge,
+                match_param=self.match_k(padded_K),
+                sensing_limit=cfg.circuit.sensing_limit,
+                threshold=float(cfg.app.match_param)
+                if cfg.app.match_type == "threshold" else 0.0)
 
     def _search_batch(self, grid, qseg, state: CAMState):
         """One fused batched search + merge over a (Q, nh, C) block."""
         cfg = self.config
-        dist, match = subarray.subarray_query_batched(
-            grid, qseg,
-            distance=cfg.app.distance,
-            sensing=cfg.circuit.sensing,
-            sensing_limit=cfg.circuit.sensing_limit,
-            threshold=float(cfg.app.match_param)
-            if cfg.app.match_type == "threshold" else 0.0,
-            col_valid=state.col_valid,
-            row_valid=state.row_valid,
-            use_kernel=self.use_kernel,
-            want_dist=self.need_dist(),
-            q_tile=self.q_tile,
-            pipeline=self.pipeline,
-            int_codes=self.int_codes)
+        with jax.named_scope("cam.search"):
+            dist, match = subarray.subarray_query_batched(
+                grid, qseg,
+                distance=cfg.app.distance,
+                sensing=cfg.circuit.sensing,
+                sensing_limit=cfg.circuit.sensing_limit,
+                threshold=float(cfg.app.match_param)
+                if cfg.app.match_type == "threshold" else 0.0,
+                col_valid=state.col_valid,
+                row_valid=state.row_valid,
+                use_kernel=self.use_kernel,
+                want_dist=self.need_dist(),
+                q_tile=self.q_tile,
+                pipeline=self.pipeline,
+                int_codes=self.int_codes)
         return self.merge_rows(dist, match, state.spec.padded_K)

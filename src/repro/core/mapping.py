@@ -79,9 +79,10 @@ def partition_rows(rows: jax.Array, spec: GridSpec) -> jax.Array:
 
 def partition_query(q: jax.Array, spec: GridSpec) -> jax.Array:
     """(..., N) -> (..., nh, C) query segments."""
-    pad = [(0, 0)] * (q.ndim - 1) + [(0, spec.padded_N - spec.N)]
-    x = jnp.pad(q, pad)
-    return x.reshape(*q.shape[:-1], spec.nh, spec.C)
+    with jax.named_scope("cam.quantize"):
+        pad = [(0, 0)] * (q.ndim - 1) + [(0, spec.padded_N - spec.N)]
+        x = jnp.pad(q, pad)
+        return x.reshape(*q.shape[:-1], spec.nh, spec.C)
 
 
 def col_valid_mask(spec: GridSpec) -> jax.Array:

@@ -441,7 +441,8 @@ def cam_search_batched_pallas(stored: jax.Array, queries: jax.Array,
                             distance=distance, sensing="exact",
                             sensing_limit=0.0, threshold=0.0, q_tile=q_tile,
                             want_dist=True, interpret=interpret,
-                            pipeline=pipeline)
+                            pipeline=pipeline,
+                            name="cam_search_batched_pallas")
     return dist
 
 
@@ -522,7 +523,7 @@ def _fused_driver(stored_planes, queries: jax.Array,
                   col_valid: jax.Array, row_valid: jax.Array, *,
                   distance: str, sensing: str, sensing_limit: float,
                   threshold: float, q_tile: Optional[int], want_dist: bool,
-                  interpret: bool, pipeline: bool):
+                  interpret: bool, pipeline: bool, name: str):
     """Shared scaffolding for the fused batched kernels (point-code grids
     pass ``stored_planes=(stored,)`` with a real distance; ACAM range grids
     pass ``(lo, hi)`` with ``distance='range'``).
@@ -549,7 +550,13 @@ def _fused_driver(stored_planes, queries: jax.Array,
     both paths, so interpret mode runs the compiled schedule); the compiled
     path passes the VMEM count (``fused_vmem_bytes``) as Mosaic's
     ``vmem_limit_bytes``, and sizes blocks with the device's entry of
-    ``DEVICE_MODELS``."""
+    ``DEVICE_MODELS``.
+
+    The ``pallas_call`` runs under the named scope ``cam.kernel``, the
+    trace's handle on the kernel (its ops' scope path holds it).  A custom
+    call takes the innermost scope's name as its HLO instruction name, so
+    ``name`` (the calling wrapper's) is restated inside that scope and the
+    instruction keeps it: ``%cam_search_fused_pallas.N``."""
     from jax.experimental.pallas import tpu as pltpu
 
     nv, nh, R, C = stored_planes[0].shape
@@ -621,16 +628,17 @@ def _fused_driver(stored_planes, queries: jax.Array,
             pl.BlockSpec((1, 1, R), lambda i, j, k: (i, 0, 0)),
         ]
         spec = pl.BlockSpec((1, 1, qt, R), lambda i, j, k: (i, j, k, 0))
-    out = pl.pallas_call(
-        body,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=(spec, spec) if want_dist else spec,
-        out_shape=(shape, shape) if want_dist else shape,
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=need(max(vb, 1)) + (4 << 20)),
-        interpret=interpret,
-    )(*planes, qs, cv, rv)
+    with jax.named_scope("cam.kernel"), jax.named_scope(name):
+        out = pl.pallas_call(
+            body,
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=(spec, spec) if want_dist else spec,
+            out_shape=(shape, shape) if want_dist else shape,
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=need(max(vb, 1)) + (4 << 20)),
+            interpret=interpret,
+        )(*planes, qs, cv, rv)
 
     def back(o):                        # (nv, nh, Qp, R) -> (Q, nv, nh, R)
         return jnp.transpose(o, (2, 0, 1, 3))[:Q]
@@ -672,7 +680,8 @@ def cam_search_fused_pallas(stored: jax.Array, queries: jax.Array,
                          sensing_limit=float(sensing_limit),
                          threshold=float(threshold),
                          q_tile=q_tile, want_dist=want_dist,
-                         interpret=interpret, pipeline=pipeline)
+                         interpret=interpret, pipeline=pipeline,
+                         name="cam_search_fused_pallas")
 
 
 # ---------------------------------------------------------------------------
@@ -724,7 +733,8 @@ def cam_range_fused_pallas(stored_lo: jax.Array, stored_hi: jax.Array,
                          sensing_limit=float(sensing_limit),
                          threshold=float(threshold),
                          q_tile=q_tile, want_dist=want_dist,
-                         interpret=interpret, pipeline=pipeline)
+                         interpret=interpret, pipeline=pipeline,
+                         name="cam_range_fused_pallas")
 
 
 # ---------------------------------------------------------------------------

@@ -132,7 +132,9 @@ class SearchRequest:
     mask: Optional[np.ndarray] = None      # (padded_K,) match lines
     slo: str = "default"                   # latency-percentile bucket
     t_submit: float = 0.0                  # perf_counter seconds
+    t_start: float = 0.0                   # its step's dispatch began
     t_done: float = 0.0
+    step: int = -1                         # search step that served it
 
     @property
     def done(self) -> bool:
@@ -154,7 +156,9 @@ class MutationRequest:
     ids: Optional[np.ndarray] = None       # delete/update target ids
     slo: str = "mutation"
     t_submit: float = 0.0
+    t_start: float = 0.0                   # its coalesced run began
     t_done: float = 0.0
+    step: int = -1                         # search step index at the run
     done: bool = False
 
 
@@ -188,8 +192,25 @@ class CAMSearchServer:
     fails anyway, its popped requests are restored to the queue front
     before the error propagates, so no request is ever silently lost.
 
-    Every request carries an ``slo`` tag and submit/finish timestamps;
-    ``latency_stats()`` reports per-tag p50/p99 request latency.
+    Every request carries an ``slo`` tag, the index of the search step
+    that served it (``step``) and three timestamps: ``t_submit``,
+    ``t_start`` (its step's dispatch, or its mutation run, began) and
+    ``t_done``.  ``latency_stats()`` reports per-tag p50/p99 of the whole
+    latency (submit to done) and of the queueing part (submit to start).
+    ``counters`` holds cumulative counts, always kept: ``steps`` (search
+    steps served), ``searches`` (search requests answered) and
+    ``fetch_bytes`` (bytes of the host arrays each step copied back).
+
+    Under ``jax.profiler`` a step records the host spans
+    ``cam.serve.step`` (all of ``step()``, with ``step_num`` = the search
+    step index, the same number as its requests' ``step``),
+    ``cam.serve.mutate`` (each coalesced mutation run), and nested in the
+    step, in order, ``cam.serve.dispatch`` (stack, pad, key fold and the
+    enqueue of the search), ``cam.serve.wait`` (until the device has
+    finished) and ``cam.serve.fetch`` (the device-to-host copy of the
+    indices and of the mask, with ``fetch_bytes`` = the step's addition
+    to ``counters["fetch_bytes"]``).  With no profiler running they record
+    nothing.
 
     ``autoscale=False`` (default) pads every step to exactly ``batch``
     queries, so each step hits one compiled search shape.  With
@@ -231,6 +252,7 @@ class CAMSearchServer:
         self.finished: List[Any] = []
         self._next_rid = 0
         self._steps = 0
+        self.counters = {"steps": 0, "searches": 0, "fetch_bytes": 0}
         self._mut_steps = 0
         self._ticks = 0      # reliability: serve steps = drift age units
 
@@ -382,6 +404,11 @@ class CAMSearchServer:
         popped requests to the queue front before re-raising.  With
         reliability enabled the store ages (and is scrubbed) every step,
         queue empty or not — drift does not wait for traffic."""
+        with jax.profiler.StepTraceAnnotation("cam.serve.step",
+                                              step_num=self._steps):
+            return self._step()
+
+    def _step(self) -> int:
         self._reliability_tick()
         if not self.queue:
             return 0
@@ -395,8 +422,12 @@ class CAMSearchServer:
                    and isinstance(self.queue[0], MutationRequest)
                    and self.queue[0].kind == run[0].kind):
                 run.append(self.queue.pop(0))
+            t_start = time.perf_counter()
+            for r in run:
+                r.step, r.t_start = self._steps, t_start
             try:
-                self._apply_mutations(run)
+                with jax.profiler.TraceAnnotation("cam.serve.mutate"):
+                    self._apply_mutations(run)
             except Exception:
                 self.queue[:0] = run
                 raise
@@ -409,28 +440,39 @@ class CAMSearchServer:
             return served
         reqs = self.queue[:n]
         del self.queue[:n]
-        try:
-            qs = np.stack([r.query for r in reqs]).astype(np.float32)
-            pad = self._padded_width(len(reqs)) - len(reqs)
-            if pad:
-                qs = np.concatenate(
-                    [qs, np.zeros((pad, qs.shape[1]), qs.dtype)])
-            step_key = jax.random.fold_in(self.key, self._steps)
-            # pad queries are real rows of the padded batch but NOT real
-            # requests: valid_count keeps them out of the cascade's
-            # shared bank routing
-            idx, mask = self.sim.query(self.state, jnp.asarray(qs),
-                                       key=step_key,
-                                       valid_count=len(reqs))
-        except Exception:
-            self.queue[:0] = reqs
-            raise
+        with jax.profiler.TraceAnnotation("cam.serve.dispatch"):
+            t_start = time.perf_counter()
+            try:
+                qs = np.stack([r.query for r in reqs]).astype(np.float32)
+                pad = self._padded_width(len(reqs)) - len(reqs)
+                if pad:
+                    qs = np.concatenate(
+                        [qs, np.zeros((pad, qs.shape[1]), qs.dtype)])
+                step_key = jax.random.fold_in(self.key, self._steps)
+                # pad queries are real rows of the padded batch but NOT
+                # real requests: valid_count keeps them out of the
+                # cascade's shared bank routing
+                idx, mask = self.sim.query(self.state, jnp.asarray(qs),
+                                           key=step_key,
+                                           valid_count=len(reqs))
+            except Exception:
+                self.queue[:0] = reqs
+                raise
+        step = self._steps
         self._steps += 1
-        idx_np, mask_np = np.asarray(idx), np.asarray(mask)
+        with jax.profiler.TraceAnnotation("cam.serve.wait"):
+            jax.block_until_ready((idx, mask))
+        fetch_bytes = idx.nbytes + mask.nbytes
+        with jax.profiler.TraceAnnotation("cam.serve.fetch",
+                                          fetch_bytes=fetch_bytes):
+            idx_np, mask_np = np.asarray(idx), np.asarray(mask)
+        self.counters["steps"] += 1
+        self.counters["searches"] += len(reqs)
+        self.counters["fetch_bytes"] += fetch_bytes
         now = time.perf_counter()
         for i, req in enumerate(reqs):
             req.indices, req.mask = idx_np[i], mask_np[i]
-            req.t_done = now
+            req.step, req.t_start, req.t_done = step, t_start, now
             self.finished.append(req)
         return served + len(reqs)
 
@@ -443,15 +485,23 @@ class CAMSearchServer:
 
     # ------------------------------------------------------------ stats
     def latency_stats(self) -> Dict[str, Dict[str, float]]:
-        """Per-SLO-tag request latency percentiles over finished requests:
-        ``{tag: {'n': count, 'p50_us': ..., 'p99_us': ...}}`` (submit →
-        finish wall time, microseconds)."""
-        by: Dict[str, List[float]] = {}
+        """Per-SLO-tag request latency percentiles over finished requests,
+        in microseconds: ``{tag: {'n', 'p50_us', 'p99_us', 'queue_p50_us',
+        'queue_p99_us'}}``.  ``p*_us`` is submit to done (the whole
+        latency), ``queue_p*_us`` submit to the start of the step (or
+        mutation run) that served the request: the wait in the queue, which
+        tells queueing apart from service."""
+        by: Dict[str, List[Tuple[float, float]]] = {}
         for r in self.finished:
-            by.setdefault(r.slo, []).append((r.t_done - r.t_submit) * 1e6)
-        return {
-            slo: {"n": float(len(v)),
-                  "p50_us": float(np.percentile(np.asarray(v), 50)),
-                  "p99_us": float(np.percentile(np.asarray(v), 99))}
-            for slo, v in by.items()
-        }
+            by.setdefault(r.slo, []).append(
+                ((r.t_done - r.t_submit) * 1e6,
+                 (r.t_start - r.t_submit) * 1e6))
+        out = {}
+        for slo, v in by.items():
+            total, queued = np.asarray(v).T
+            out[slo] = {"n": float(len(v)),
+                        "p50_us": float(np.percentile(total, 50)),
+                        "p99_us": float(np.percentile(total, 99)),
+                        "queue_p50_us": float(np.percentile(queued, 50)),
+                        "queue_p99_us": float(np.percentile(queued, 99))}
+        return out

@@ -602,6 +602,137 @@ def test_cam_search_server_latency_stats_by_slo():
     assert stats["interactive"]["n"] == 2 and stats["batch"]["n"] == 3
     for s in stats.values():
         assert 0 <= s["p50_us"] <= s["p99_us"]
+        # the queueing part (submit to start) is a part of the whole
+        assert 0 <= s["queue_p50_us"] <= s["queue_p99_us"]
+        assert s["queue_p50_us"] <= s["p50_us"]
+        assert s["queue_p99_us"] <= s["p99_us"]
+    # the queueing percentiles read the submit-to-start times
+    batch = [r for r in srv.finished if r.slo == "batch"]
+    waits = [(r.t_start - r.t_submit) * 1e6 for r in batch]
+    assert stats["batch"]["queue_p99_us"] == pytest.approx(
+        float(np.percentile(waits, 99)))
+
+
+def test_cam_search_server_counters_count_steps_requests_and_bytes():
+    from repro.core import FunctionalSimulator
+    from repro.runtime import CAMSearchServer
+
+    sim = FunctionalSimulator(_cam_server_cfg())
+    state = sim.write(jax.random.uniform(KEY, (30, 16)))
+    srv = CAMSearchServer(sim, state, batch=4)
+    assert srv.counters == {"steps": 0, "searches": 0, "fetch_bytes": 0}
+    queries = np.asarray(jax.random.uniform(jax.random.PRNGKey(3),
+                                            (12, 16)))
+    reqs = [srv.submit(q) for q in queries]
+    srv.run()
+    handed = sum(r.indices.nbytes + r.mask.nbytes for r in reqs)
+    assert srv.counters == {"steps": 3, "searches": 12,
+                            "fetch_bytes": handed}
+    # a part-filled step fetches its whole padded batch
+    srv.submit(queries[0])
+    srv.step()
+    k, padded_K = reqs[0].indices.shape[0], reqs[0].mask.shape[0]
+    assert srv.counters["steps"] == 4 and srv.counters["searches"] == 13
+    assert srv.counters["fetch_bytes"] == handed + 4 * (k + padded_K) * 4
+    srv.step()                                   # empty queue: no step
+    assert srv.counters["steps"] == 4
+
+
+def test_cam_search_server_requests_carry_their_step_and_start():
+    from repro.core import FunctionalSimulator
+    from repro.runtime import CAMSearchServer
+
+    cfg = _cam_server_cfg().replace(sim=dict(capacity=48))
+    sim = FunctionalSimulator(cfg)
+    state = sim.write(jax.random.uniform(KEY, (30, 16)))
+    srv = CAMSearchServer(sim, state, batch=4)
+    searches = [srv.submit(np.full(16, i / 10, np.float32))
+                for i in range(6)]
+    ins = srv.submit_insert(np.ones((2, 16), np.float32))
+    late = srv.submit(np.zeros(16, np.float32))
+    srv.run()
+    assert [r.step for r in searches] == [0, 0, 0, 0, 1, 1]
+    assert ins.step == 2 and late.step == 2     # the run rides step 2
+    for r in searches + [ins, late]:
+        assert r.t_submit <= r.t_start <= r.t_done
+    assert searches[0].t_start == searches[3].t_start
+    assert searches[3].t_done <= searches[4].t_start
+    assert ins.t_start <= late.t_start
+
+
+def test_cam_search_server_step_spans_nest_in_order(tmp_path):
+    """A profiled step holds the serve engine's spans, read back by the
+    benchmark's trace reader: dispatch, wait and fetch inside the step,
+    one after another."""
+    import glob
+
+    from bench import program_trace
+    from bench import trace as tr
+    from repro.core import FunctionalSimulator
+    from repro.runtime import CAMSearchServer
+
+    sim = FunctionalSimulator(_cam_server_cfg())
+    state = sim.write(jax.random.uniform(KEY, (30, 16)))
+    srv = CAMSearchServer(sim, state, batch=4)
+    for _ in range(2):                           # compile outside the trace
+        srv.submit(np.zeros(16, np.float32))
+    srv.run()
+    for _ in range(4):
+        srv.submit(np.zeros(16, np.float32))
+    jax.profiler.start_trace(str(tmp_path),
+                             profiler_options=tr.capture_options())
+    before = srv.counters["fetch_bytes"]
+    srv.step()
+    jax.profiler.stop_trace()
+    t = tr.load(str(tmp_path))
+    step = t.span("cam.serve.step")
+    assert step is not None
+    inner = [t.span(n) for n in ("cam.serve.dispatch", "cam.serve.wait",
+                                 "cam.serve.fetch")]
+    assert all(e is not None for e in inner)
+    assert step.start <= inner[0].start
+    for a, b in zip(inner, inner[1:]):
+        assert a.end <= b.start
+    assert inner[-1].end <= step.end
+    assert all(e.depth > step.depth for e in inner)
+    assert [r.step for r in srv.finished[-4:]] == [1, 1, 1, 1]
+    # the step span names its step; the fetch span, the bytes it fetched
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    stats = {s.name: s.stats for s in program_trace.host_spans(path)}
+    assert stats["cam.serve.step"]["step_num"] == 1
+    assert stats["cam.serve.fetch"] == {
+        "fetch_bytes": srv.counters["fetch_bytes"] - before}
+
+
+def _scopes(sim, state, queries):
+    import re
+    text = type(sim)._query_jit.lower(
+        sim, state, queries, jax.random.PRNGKey(1), None).compile().as_text()
+    # a scope under a transform reads "vmap(cam.merge)"
+    return {scope for name in re.findall(r'op_name="([^"]*)"', text)
+            for scope in re.findall(r"(?:^|[/(])(cam\.[a-z]+)", name)}
+
+
+@pytest.mark.parametrize("variation", ["none", "c2c"])
+def test_search_program_carries_its_named_scopes(variation):
+    from repro.core import FunctionalSimulator
+
+    sim = FunctionalSimulator(_cam_server_cfg(variation))
+    state = sim.write(jax.random.uniform(KEY, (30, 16)), KEY)
+    assert state.perm is None
+    assert _scopes(sim, state, jnp.zeros((4, 16))) == {
+        "cam.quantize", "cam.search", "cam.merge"}
+
+
+def test_search_program_scopes_the_back_map_where_rows_are_placed():
+    from repro.core import FunctionalSimulator
+
+    cfg = _cam_server_cfg().replace(sim=dict(prefilter="ivf"))
+    sim = FunctionalSimulator(cfg)
+    state = sim.write(jax.random.uniform(KEY, (30, 16)), KEY)
+    assert state.perm is not None
+    assert _scopes(sim, state, jnp.zeros((4, 16))) == {
+        "cam.quantize", "cam.search", "cam.merge", "cam.backmap"}
 
 
 # ---------------------------------------------------------------------------
