@@ -363,8 +363,8 @@ class ShardedCAMSimulator:
                     sub_grid, qseg_l, col_valid=col_valid, row_valid=sub_rv,
                     key=key, cycle_keys=cycle_keys_for(key),
                     bank_ids=b_idx * nv_loc + local_ids)
-                return self._combine_selected(dist, match, local_ids,
-                                              b_idx, nv_loc, R, K_pad, k)
+                return self._combine(dist, match, b_idx, nv_loc, R,
+                                     K_pad, k, local_ids)
 
             return jax.shard_map(
                 body, mesh=self.mesh,
@@ -392,11 +392,21 @@ class ShardedCAMSimulator:
             state.grid, state.row_valid, state.col_valid, qseg, key)
 
     def _combine(self, dist, match, b_idx, nv_loc: int, R: int,
-                 K_pad: int, k: int):
+                 K_pad: int, k: int, local_ids=None):
         """Cross-device vertical merge of shard-local subarray outputs.
 
         Mirrors ``merge.merge`` (same h-reduce, same stable comparator
         ordering) with the nv reduction distributed over the bank axis.
+        ``local_ids`` names the (p_loc,) local banks a routed search ran
+        on (the cascade): results are put back into the device's full
+        (nv_loc, R) frame, so the collective payloads keep their shapes
+        (``merge.shard_merge_payload`` still models them); with
+        ``p_loc = nv_loc`` and sorted ids this is bit-identical to the
+        full scan.
+
+        The device-local work carries the scope ``cam.merge`` (as the
+        single-device merge does), the collectives and the re-rank
+        ``cam.shard.exchange``.
         """
         cfg = self.config
         ba = self.bank_axis
@@ -407,14 +417,21 @@ class ShardedCAMSimulator:
             if cfg.arch.v_merge != "gather":
                 raise ValueError(
                     f"{cfg.app.match_type} match uses gather v-merge")
-            row = merge.h_reduce_match(
-                dist, match, match_type=cfg.app.match_type,
-                h_merge=cfg.arch.h_merge,
-                sensing_limit=cfg.circuit.sensing_limit, threshold=thr)
-            # lossless gather: concatenate the per-bank match-line blocks
-            rows = jax.lax.all_gather(row, ba, axis=1, tiled=True)
-            mask = merge.v_merge_gather(rows)               # (Q, K_pad)
-            return merge.first_k_indices(mask, k), mask
+            with jax.named_scope("cam.merge"):
+                row = merge.h_reduce_match(
+                    dist, match, match_type=cfg.app.match_type,
+                    h_merge=cfg.arch.h_merge,
+                    sensing_limit=cfg.circuit.sensing_limit, threshold=thr)
+                if local_ids is not None:
+                    # unselected local banks read as unmatched
+                    row = merge.scatter_match_rows(row, local_ids, nv_loc)
+                    row = row.reshape(*row.shape[:-1], nv_loc, R)
+            with jax.named_scope("cam.shard.exchange"):
+                # lossless gather: concatenate the per-bank match lines
+                rows = jax.lax.all_gather(row, ba, axis=1, tiled=True)
+            with jax.named_scope("cam.merge"):
+                mask = merge.v_merge_gather(rows)           # (Q, K_pad)
+                return merge.first_k_indices(mask, k), mask
 
         if cfg.app.match_type != "best":
             raise ValueError(f"unknown match_type {cfg.app.match_type!r}")
@@ -423,66 +440,29 @@ class ShardedCAMSimulator:
         dmax = None
         if cfg.arch.h_merge == "voting":
             # tie-break normalizer over ALL banks: one scalar-ish pmax
-            dmax = jax.lax.pmax(merge.voting_dmax(dist), ba)
-        values, largest = merge.h_reduce_best(
-            dist, match, h_merge=cfg.arch.h_merge, dmax=dmax)
-        vals, gidx = merge.local_topk_candidates(
-            values, k, largest=largest, row_offset=b_idx * nv_loc * R)
-        return self._comparator_tail(vals, gidx, k, K_pad, largest)
-
-    def _comparator_tail(self, vals, gidx, k: int, K_pad: int,
-                         largest: bool):
-        """Cross-device comparator tree: gather only the candidate scores
-        + global indices, stable re-rank, finalize."""
-        ba = self.bank_axis
-        av = jax.lax.all_gather(vals, ba)            # (n_banks, Q, k_l)
-        ai = jax.lax.all_gather(gidx, ba)
-        av = jnp.moveaxis(av, 0, -2).reshape(*vals.shape[:-1], -1)
-        ai = jnp.moveaxis(ai, 0, -2).reshape(*gidx.shape[:-1], -1)
-        best_v, best_i = merge.rerank_candidates(av, ai, k, largest=largest)
-        return merge.finalize_topk(best_v, best_i, largest=largest,
-                                   K=K_pad)
-
-    def _combine_selected(self, dist, match, local_ids, b_idx, nv_loc: int,
-                          R: int, K_pad: int, k: int):
-        """``_combine`` for this device's routed (p_loc, nh, R) bank
-        subset: scatter/offset results back into the device's full
-        (nv_loc, R) coordinate frame, then the SAME cross-device merge as
-        the full scan (the collective payload shapes are unchanged, so
-        ``merge.shard_merge_payload`` still models them).  With
-        ``p_loc = nv_loc`` and sorted ids this is bit-identical to
-        ``_combine``.
-        """
-        cfg = self.config
-        ba = self.bank_axis
-        thr = (float(cfg.app.match_param)
-               if cfg.app.match_type == "threshold" else 0.0)
-
-        if cfg.app.match_type in ("exact", "threshold"):
-            if cfg.arch.v_merge != "gather":
-                raise ValueError(
-                    f"{cfg.app.match_type} match uses gather v-merge")
-            row = merge.h_reduce_match(
-                dist, match, match_type=cfg.app.match_type,
-                h_merge=cfg.arch.h_merge,
-                sensing_limit=cfg.circuit.sensing_limit, threshold=thr)
-            # unselected local banks read as unmatched in the gathered rows
-            full = merge.scatter_match_rows(row, local_ids, nv_loc)
-            rows = full.reshape(*full.shape[:-1], nv_loc, R)
-            rows = jax.lax.all_gather(rows, ba, axis=1, tiled=True)
-            mask = merge.v_merge_gather(rows)               # (Q, K_pad)
-            return merge.first_k_indices(mask, k), mask
-
-        if cfg.app.match_type != "best":
-            raise ValueError(f"unknown match_type {cfg.app.match_type!r}")
-        if cfg.arch.v_merge != "comparator":
-            raise ValueError("best match requires comparator v-merge")
-        dmax = None
-        if cfg.arch.h_merge == "voting":
-            dmax = jax.lax.pmax(merge.voting_dmax(dist), ba)
-        values, largest = merge.h_reduce_best(
-            dist, match, h_merge=cfg.arch.h_merge, dmax=dmax)
-        vals, gidx = merge.selected_topk(
-            values, k, largest=largest, bank_ids=local_ids,
-            bank_offset=b_idx * nv_loc)
-        return self._comparator_tail(vals, gidx, k, K_pad, largest)
+            with jax.named_scope("cam.merge"):
+                dmax = merge.voting_dmax(dist)
+            with jax.named_scope("cam.shard.exchange"):
+                dmax = jax.lax.pmax(dmax, ba)
+        with jax.named_scope("cam.merge"):
+            values, largest = merge.h_reduce_best(
+                dist, match, h_merge=cfg.arch.h_merge, dmax=dmax)
+            if local_ids is None:
+                vals, gidx = merge.local_topk_candidates(
+                    values, k, largest=largest, row_offset=b_idx * nv_loc * R)
+            else:
+                vals, gidx = merge.selected_topk(
+                    values, k, largest=largest, bank_ids=local_ids,
+                    bank_offset=b_idx * nv_loc)
+        with jax.named_scope("cam.shard.exchange"):
+            # comparator tree: gather only the candidate scores + global
+            # indices, then a stable re-rank (replicated on every device)
+            av = jax.lax.all_gather(vals, ba)        # (n_banks, Q, k_l)
+            ai = jax.lax.all_gather(gidx, ba)
+            av = jnp.moveaxis(av, 0, -2).reshape(*vals.shape[:-1], -1)
+            ai = jnp.moveaxis(ai, 0, -2).reshape(*gidx.shape[:-1], -1)
+            best_v, best_i = merge.rerank_candidates(av, ai, k,
+                                                     largest=largest)
+        with jax.named_scope("cam.merge"):
+            return merge.finalize_topk(best_v, best_i, largest=largest,
+                                       K=K_pad)

@@ -296,6 +296,72 @@ def test_sharded_parity_4_devices():
         (proc.stdout[-2000:], proc.stderr[-4000:])
 
 
+# best match across shards: many rows tie, across shard boundaries too,
+# and nv is not always a multiple of the bank axis; indices and mask stay
+# bit-identical to the single-device reference, and the only collectives
+# move the (banks, Q, k) candidates, never a (Q, K) block
+_SHARD_TIES_SCRIPT = r'''
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+os.environ["JAX_PLATFORMS"] = "cpu"
+import re
+import jax, jax.numpy as jnp, numpy as np
+from repro.core import (AppConfig, ArchConfig, CAMConfig, CircuitConfig,
+                        DeviceConfig, FunctionalSimulator,
+                        ShardedCAMSimulator)
+from repro.launch.mesh import make_cam_mesh
+
+R = 8
+cfg = CAMConfig(
+    app=AppConfig(distance="l2", match_type="best", match_param=5,
+                  data_bits=3),
+    arch=ArchConfig(h_merge="adder", v_merge="comparator"),
+    circuit=CircuitConfig(rows=R, cols=8, cell_type="mcam", sensing="best"),
+    device=DeviceConfig(device="fefet"))
+n = 0
+for K in (37, 64, 100):        # nv = 5, 8, 13: padded to 8, 8, 16 banks
+    for use_kernel in (False, True):
+        for banks, qs in ((4, 1), (2, 2)):
+            sim_cfg = dict(use_kernel=use_kernel, c2c_fold="bank")
+            ref = FunctionalSimulator(cfg.replace(sim=sim_cfg))
+            qa = "query" if qs > 1 else None
+            ssim = ShardedCAMSimulator(cfg.replace(sim=sim_cfg),
+                                       make_cam_mesh(banks, qs),
+                                       query_axis=qa)
+            # few distinct rows repeated over every bank and device: most
+            # distances tie, across shard boundaries too
+            base = jax.random.uniform(jax.random.PRNGKey(K), (7, 12))
+            stored = jnp.tile(base, (-(-K // 7), 1))[:K]
+            queries = jax.random.uniform(jax.random.PRNGKey(K + 1), (8, 12))
+            state = ssim.write(stored)
+            want = ref.query(ref.write(stored), queries)
+            got = ssim.query(state, queries)
+            tag = f"K={K} kernel={use_kernel} mesh={banks}x{qs}"
+            np.testing.assert_array_equal(np.asarray(want.indices),
+                                          np.asarray(got.indices), tag)
+            np.testing.assert_array_equal(np.asarray(want.mask),
+                                          np.asarray(got.mask), tag)
+            assert got.mask.shape == (8, state.spec.padded_K), tag
+            text = type(ssim)._query_jit.lower(
+                ssim, state, queries, jax.random.PRNGKey(1),
+                None).compile().as_text()
+            for line in text.splitlines():
+                if re.search(r"= \S+ all-gather\(", line):
+                    shape = re.search(r"= \w+\[([\d,]*)\]", line).group(1)
+                    assert np.prod([int(x) for x in shape.split(",")]) \
+                        <= banks * 8 * 5, (tag, line[:160])
+            n += 1
+print(f"SHARD_TIES_OK {n}")
+'''
+
+
+@pytest.mark.multidevice
+def test_best_match_ties_across_shards_4_devices():
+    proc = _run_subprocess(_SHARD_TIES_SCRIPT, timeout=600)
+    assert proc.returncode == 0 and "SHARD_TIES_OK 12" in proc.stdout, \
+        (proc.stdout[-2000:], proc.stderr[-4000:])
+
+
 # ---------------------------------------------------------------------------
 # merge invariants (pure functions — no devices needed)
 # ---------------------------------------------------------------------------
