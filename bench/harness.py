@@ -255,14 +255,13 @@ def per_layer_values(cell: Cell, ctx) -> dict:
     return out
 
 
-def trace_context(cell: Cell, win, trace_dir: str, chips: int) -> tuple:
+def trace_context(cell: Cell, win, t, chips: int) -> tuple:
     """Context the per-layer readers see, plus the run's device record
-    and breakdown from the trace."""
+    and breakdown, from the loaded trace ``t`` (``bench/trace.load``)."""
     import jax
 
     from bench import trace as tr
     from bench import work
-    t = tr.load(trace_dir)
     span = t.span("bench.window")
     if span is None:
         raise RuntimeError("the trace holds no bench.window span")
@@ -274,7 +273,7 @@ def trace_context(cell: Cell, win, trace_dir: str, chips: int) -> tuple:
     q = cell.config["cam"]["sim"]["serve_batch"]
     ctx = SimpleNamespace(
         window=win, trace=t, ops=ops, lo=lo, hi=hi, n_steps=n,
-        chips=len(planes), kind=win.kind, tr=tr,
+        chips=len(planes), kind=win.kind, tr=tr, n_gaps=0,
         work=work.search_work(cell.config, q),
         device_kind=jax.devices()[0].device_kind)
     window_s = (hi - lo) / 1e9
@@ -283,8 +282,10 @@ def trace_context(cell: Cell, win, trace_dir: str, chips: int) -> tuple:
     all_ops = [e for p in planes for e in ops[p]]
     idle = {}
     for p in planes:
-        for s, e in tr.gaps(ops[p], lo, hi):
-            who = tr.host_activity(t.host, s, e, skip=("bench.window",))
+        gaps = tr.gaps(ops[p], lo, hi)
+        ctx.n_gaps += len(gaps)
+        for (s, e), who in zip(gaps, tr.attribute_gaps(
+                t.host, gaps, skip=("bench.window",))):
             idle[who] = idle.get(who, 0.0) + (e - s) / 1e9 / len(planes)
     breakdown = {
         "device_ops": [[n[:OP_NAME_CHARS], v] for n, v in tr.top_by_name(
@@ -334,9 +335,21 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     in_setup = dict(compiles.n)
     win = loadgen.run(srv, queries, cell.mix, seconds, rng=rng,
                       annotate=annotate, marks=window_span(annotate))
+    phases = {}
+
+    def phase(name: str, secs: float) -> None:
+        """Record a phase's seconds and log it as it ends, so a run cut
+        short shows how far it got."""
+        phases[name] = secs
+        log(f"phase {name} {secs:.3f} s")
+    phase("setup", setup_s)
+    phase("window", win.seconds)
+    phase("drain", time.perf_counter() - win.t_close)
     in_window = compiles.since(in_setup)
     if trace:
+        t = time.perf_counter()
         jax.profiler.stop_trace()
+        phase("stop_trace", time.perf_counter() - t)
     peak = peak_bytes(devices)
     spec = srv.state.spec
     steps = np.asarray([e - s for s, e, _ in win.window_steps()]) * 1e3
@@ -352,6 +365,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     del srv, spec
     gc.collect()
     checks = check(cell, seed, win)
+    phase("reference", checks["_seconds"])
     log("reference: {} answers to {} distinct queries checked in {:.2f} s;"
         " {} with a gap above 0, {} above the limit".format(*(
             checks.pop(k) for k in ("_answers", "_queries", "_seconds",
@@ -364,8 +378,11 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     device = {"platform": dev[0].platform, "kind": dev[0].device_kind,
               "count": len(dev), "memory_peak_bytes": peak}
     if trace:
-        ctx, extra, breakdown = trace_context(cell, win, trace_dir,
-                                              cell.chips)
+        t = time.perf_counter()
+        loaded = tr.load(trace_dir)
+        phase("trace_load", time.perf_counter() - t)
+        t = time.perf_counter()
+        ctx, extra, breakdown = trace_context(cell, win, loaded, cell.chips)
         device.update(extra)
         if ctx.ops:                       # a device trace: peaks exist
             least, bound = work.least_time(
@@ -374,6 +391,11 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
                 f"{bound} bound; device busy {extra['busy_s']:.3f} s of "
                 f"{extra['window_s']:.3f} s")
         result["metrics"] = per_layer_values(cell, ctx)
+        phase("reduce", time.perf_counter() - t)
+        log(f"trace: {ctx.n_gaps} device idle gaps and {len(loaded.host)} "
+            f"host events over {ctx.chips} chip(s) and {ctx.n_steps} steps "
+            f"({ctx.n_gaps / max(1, ctx.chips) / ctx.n_steps:.2f} gaps and "
+            f"{len(loaded.host) / ctx.n_steps:.1f} host events a step)")
         result["device"] = device
         result["breakdown"] = breakdown
     else:
@@ -389,6 +411,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
                                                 "unit": m["unit"]}
         result["device"] = device
     result["checks"] = checks
+    log("phases s: " + ", ".join(f"{k} {v:.3f}" for k, v in phases.items()))
     for name, c in checks.items():
         log(f"check {name} {c['value']!r} limit {c['limit']!r}")
     return result

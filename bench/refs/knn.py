@@ -30,6 +30,8 @@ quantizes the queries in bfloat16; ``int4`` casts the codes through int4.
 from __future__ import annotations
 
 import functools
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -37,6 +39,7 @@ import numpy as np
 N_CAND_EXTRA = 22         # candidates beyond k, re-ranked in float64
 ROW_BLOCK = 8192          # rows per first-stage top-k block
 QUERY_BLOCK = 128         # queries per device pass
+GATHER_ROWS = 1 << 16     # rows gathered per block of the float64 check
 
 
 def supported(cam: dict) -> None:
@@ -164,22 +167,36 @@ def all_candidates(rows, qcodes, *, distance: str, n_cand: int,
     return np.concatenate(out)
 
 
-def exact(rows, qcodes: np.ndarray, ids: np.ndarray, distance: str
-          ) -> np.ndarray:
-    """float64 distances of queries (S, N) to rows ``ids`` (S, m); inf for
-    an id out of range or repeated within its query."""
+def exact(rows, qcodes: np.ndarray, ids: np.ndarray, distance: str,
+          qidx: Optional[np.ndarray] = None) -> np.ndarray:
+    """float64 distances of queries to rows ``ids`` (S, m); inf for an id
+    out of range or repeated within its query.  Row ``i`` is query
+    ``qcodes[qidx[i]]`` (``qcodes[i]`` without ``qidx``).  Computed in
+    blocks of ``GATHER_ROWS`` gathered rows, each gather padded to that
+    one shape, so device and host memory stay bounded."""
     K = rows.shape[0]
     ids = np.asarray(ids, np.int64)
-    ok = (ids >= 0) & (ids < K)
-    dup = np.zeros(ids.shape, bool)
-    for j in range(1, ids.shape[1]):
-        dup[:, j] = (ids[:, :j] == ids[:, j:j + 1]).any(axis=1)
-    safe = np.where(ok, ids, 0)
-    g = np.asarray(jnp.take(rows, jnp.asarray(safe.reshape(-1)), axis=0),
-                   np.float64).reshape(*ids.shape, -1)
-    q = np.asarray(qcodes, np.float64)[:, None, :]
-    d = ((g - q) ** 2).sum(-1) if distance == "l2" else -(g * q).sum(-1)
-    return np.where(ok & ~dup, d, np.inf)
+    qn = np.asarray(qcodes)
+    S, m = ids.shape
+    out = np.empty((S, m), np.float64)
+    per = max(1, GATHER_ROWS // max(1, m))
+    for a in range(0, S, per):
+        blk = ids[a:a + per]
+        ok = (blk >= 0) & (blk < K)
+        dup = np.zeros(blk.shape, bool)
+        for j in range(1, m):
+            dup[:, j] = (blk[:, :j] == blk[:, j:j + 1]).any(axis=1)
+        safe = np.where(ok, blk, 0).reshape(-1)
+        flat = np.zeros(max(GATHER_ROWS, safe.size), np.int64)
+        flat[:safe.size] = safe
+        g = np.asarray(jnp.take(rows, jnp.asarray(flat), axis=0)
+                       )[:safe.size].astype(np.float64).reshape(
+                           *blk.shape, -1)
+        q = np.asarray(qn[a:a + per] if qidx is None
+                       else qn[qidx[a:a + per]], np.float64)[:, None, :]
+        d = ((g - q) ** 2).sum(-1) if distance == "l2" else -(g * q).sum(-1)
+        out[a:a + per] = np.where(ok & ~dup, d, np.inf)
+    return out
 
 
 def gaps(rows, qcodes, cand: np.ndarray, qidx: np.ndarray,
@@ -189,15 +206,21 @@ def gaps(rows, qcodes, cand: np.ndarray, qidx: np.ndarray,
 
     ``qcodes`` (U, N) are the distinct queries and ``cand`` (U, m) their
     reference candidates; answer ``i`` served ``served[i]`` for query
-    ``qidx[i]``."""
+    ``qidx[i]``.  Each distinct (query, served ids) pair is checked once
+    and its gap given to every answer that repeats it."""
     k = served.shape[1]
     qn = np.asarray(qcodes)
     d_ref = np.sort(exact(rows, qn, cand, distance), axis=1)[:, :k]
-    d_srv = np.sort(exact(rows, qn[qidx], served, distance), axis=1)
+    pairs, inv = np.unique(
+        np.column_stack([np.asarray(qidx, np.int64),
+                         np.asarray(served, np.int64)]),
+        axis=0, return_inverse=True)
+    d_srv = np.sort(exact(rows, qn, pairs[:, 1:], distance,
+                          qidx=pairs[:, 0]), axis=1)
     with np.errstate(invalid="ignore"):
-        g = d_srv - d_ref[qidx]
+        g = d_srv - d_ref[pairs[:, 0]]
     g = np.where(np.isnan(g), np.inf, g)
-    return g.max(axis=1)
+    return g.max(axis=1)[inv.reshape(-1)]
 
 
 class Reference:

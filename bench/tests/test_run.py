@@ -72,9 +72,17 @@ def test_trace_run_reports_per_layer_metrics_it_can_read(tmp_path,
     root = make_checkout(str(tmp_path), {
         "t.poisson": ("sift1m-mcam3-l2-d2d", POISSON)})
     cell = harness.load_cell("t.poisson", root)
+    lines = []
     res = harness.run(cell, 3, 1.0, True, time.perf_counter(),
-                      allow_cpu=True, log=lambda s: None)
+                      allow_cpu=True, log=lines.append)
     assert res["correct"] is True
+    # each phase is logged as it ends, then all in one line, then the checks
+    ended = [s.split()[1] for s in lines if s.startswith("phase ")]
+    assert ended == ["setup", "window", "drain", "stop_trace", "reference",
+                     "trace_load", "reduce"]
+    summary = next(i for i, s in enumerate(lines) if s.startswith("phases"))
+    assert all(name in lines[summary] for name in ended)
+    assert [s.split()[1] for s in lines[summary + 1:]] == list(res["checks"])
     # the CPU trace has no device plane: only host-clock metrics read
     assert set(res["metrics"]) == {"step_ms.closed"} or \
         set(res["metrics"]) <= {"step_ms.closed", "step_ms.open",

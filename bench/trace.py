@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import bisect
 import glob
+import heapq
 import os
 import re
 from dataclasses import dataclass, field
@@ -148,17 +149,44 @@ def host_activity(host: Sequence[Event], s: float, t: float,
                   skip: Sequence[str] = ()) -> str:
     """Name of the deepest host event that overlaps [s, t] the most: what
     the host was doing while the device sat idle."""
-    best, key = "(no host span)", (-1, -1.0)
-    for e in host:
-        if e.name in skip:
-            continue
-        ov = min(e.end, t) - max(e.start, s)
-        if ov <= 0:
-            continue
-        k = (e.depth, ov) if ov >= 0.5 * (t - s) else (-1, ov)
-        if k > key:
-            best, key = e.name, k
-    return best
+    return attribute_gaps(host, [(s, t)], skip)[0]
+
+
+def attribute_gaps(host: Sequence[Event],
+                   gaps: Sequence[Tuple[float, float]],
+                   skip: Sequence[str] = ()) -> List[str]:
+    """``host_activity`` of each gap, in the gaps' order, in one sweep.
+
+    An event wins a gap by the key (depth, overlap) where it overlaps at
+    least half of the gap, else (-1, overlap); of equal keys the event
+    earliest in ``host`` wins.  The events are sorted by start once and
+    walked with the gaps in order of their start; a heap keyed by end
+    holds those that have started and not yet ended, which are the only
+    ones that can overlap the gap.  The cost is O((G + H) log H) plus one
+    step per pair of a gap and an event that overlaps it, not O(G H)."""
+    order = sorted((i for i, e in enumerate(host) if e.name not in skip),
+                   key=lambda i: host[i].start)
+    out = ["(no host span)"] * len(gaps)
+    active: List[Tuple[float, int]] = []          # (end, index in host)
+    nxt = 0
+    for g in sorted(range(len(gaps)), key=lambda g: gaps[g][0]):
+        s, t = gaps[g]
+        while nxt < len(order) and host[order[nxt]].start < t:
+            heapq.heappush(active, (host[order[nxt]].end, order[nxt]))
+            nxt += 1
+        while active and active[0][0] <= s:       # later gaps start later
+            heapq.heappop(active)
+        key = (-1, -1.0, 0)
+        for _, i in active:
+            e = host[i]
+            ov = min(e.end, t) - max(e.start, s)
+            if ov <= 0:
+                continue
+            k = ((e.depth, ov, -i) if ov >= 0.5 * (t - s)
+                 else (-1, ov, -i))
+            if k > key:
+                out[g], key = e.name, k
+    return out
 
 
 def matching(events: Iterable[Event], pattern: str, *,
