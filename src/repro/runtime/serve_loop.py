@@ -129,16 +129,38 @@ class SearchRequest:
     rid: int
     query: np.ndarray
     indices: Optional[np.ndarray] = None   # (k,) matched entries, -1 padded
-    mask: Optional[np.ndarray] = None      # (padded_K,) match lines
     slo: str = "default"                   # latency-percentile bucket
     t_submit: float = 0.0                  # perf_counter seconds
     t_start: float = 0.0                   # its step's dispatch began
     t_done: float = 0.0
     step: int = -1                         # search step that served it
+    # the fetched (exact/threshold) or assigned mask row, and for a
+    # best-match answer the (padded_K, dtype) of the row to rebuild
+    _row: Optional[np.ndarray] = field(default=None, init=False, repr=False)
+    _rebuild: Optional[Tuple[int, Any]] = field(default=None, init=False,
+                                                repr=False)
 
     @property
     def done(self) -> bool:
         return self.indices is not None
+
+    @property
+    def mask(self) -> Optional[np.ndarray]:
+        """(padded_K,) match lines.  For a best-match store it is derived
+        on each read from ``indices`` (1.0 at every index >= 0): the same
+        values, dtype and shape as the row ``sim.query`` returns, with no
+        per-request (padded_K,) array held.  Assigning it (``None``
+        included) replaces whatever the request holds."""
+        if self._rebuild is None:
+            return self._row
+        width, dtype = self._rebuild
+        row = np.zeros(width, dtype)
+        row[self.indices[self.indices >= 0]] = 1
+        return row
+
+    @mask.setter
+    def mask(self, row: Optional[np.ndarray]) -> None:
+        self._row, self._rebuild = row, None
 
 
 @dataclass
@@ -198,8 +220,19 @@ class CAMSearchServer:
     ``t_done``.  ``latency_stats()`` reports per-tag p50/p99 of the whole
     latency (submit to done) and of the queueing part (submit to start).
     ``counters`` holds cumulative counts, always kept: ``steps`` (search
-    steps served), ``searches`` (search requests answered) and
-    ``fetch_bytes`` (bytes of the host arrays each step copied back).
+    steps served), ``searches`` (search requests answered),
+    ``fetch_bytes`` (bytes of the host arrays each step copied back) and
+    ``mask_steps`` (search steps that copied the dense (Q, padded_K)
+    mask: 0 on a best-match store, ``steps`` on an exact or threshold
+    one).
+
+    What a step copies to the host follows the store's match type
+    (``config.app.match_type``).  A best-match mask holds nothing but the
+    top-k indices scattered over the rows, so a best-match step copies
+    only the (Q, k) indices and each request's ``mask`` is rebuilt from
+    its own indices when read, equal to the row ``sim.query`` returns.
+    An exact or threshold mask carries every match, not just the first
+    k, so those steps copy the indices and the mask.
 
     Under ``jax.profiler`` a step records the host spans
     ``cam.serve.step`` (all of ``step()``, with ``step_num`` = the search
@@ -208,9 +241,9 @@ class CAMSearchServer:
     step, in order, ``cam.serve.dispatch`` (stack, pad, key fold and the
     enqueue of the search), ``cam.serve.wait`` (until the device has
     finished) and ``cam.serve.fetch`` (the device-to-host copy of the
-    indices and of the mask, with ``fetch_bytes`` = the step's addition
-    to ``counters["fetch_bytes"]``).  With no profiler running they record
-    nothing.
+    indices, and of the mask on an exact or threshold store, with
+    ``fetch_bytes`` = the step's addition to ``counters["fetch_bytes"]``).
+    With no profiler running they record nothing.
 
     ``autoscale=False`` (default) pads every step to exactly ``batch``
     queries, so each step hits one compiled search shape.  With
@@ -252,7 +285,10 @@ class CAMSearchServer:
         self.finished: List[Any] = []
         self._next_rid = 0
         self._steps = 0
-        self.counters = {"steps": 0, "searches": 0, "fetch_bytes": 0}
+        self.counters = {"steps": 0, "searches": 0, "fetch_bytes": 0,
+                         "mask_steps": 0}
+        app = getattr(cfg, "app", None)
+        self._best_match = getattr(app, "match_type", None) == "best"
         self._mut_steps = 0
         self._ticks = 0      # reliability: serve steps = drift age units
 
@@ -462,16 +498,23 @@ class CAMSearchServer:
         self._steps += 1
         with jax.profiler.TraceAnnotation("cam.serve.wait"):
             jax.block_until_ready((idx, mask))
-        fetch_bytes = idx.nbytes + mask.nbytes
+        # a best-match mask is the indices scattered over padded_K rows:
+        # copy the indices alone and let each request rebuild its row
+        fetched = (idx,) if self._best_match else (idx, mask)
+        fetch_bytes = sum(a.nbytes for a in fetched)
         with jax.profiler.TraceAnnotation("cam.serve.fetch",
                                           fetch_bytes=fetch_bytes):
-            idx_np, mask_np = np.asarray(idx), np.asarray(mask)
+            host = [np.asarray(a) for a in fetched]
         self.counters["steps"] += 1
         self.counters["searches"] += len(reqs)
         self.counters["fetch_bytes"] += fetch_bytes
+        self.counters["mask_steps"] += int(not self._best_match)
+        rebuild = (mask.shape[-1], mask.dtype) if self._best_match else None
         now = time.perf_counter()
         for i, req in enumerate(reqs):
-            req.indices, req.mask = idx_np[i], mask_np[i]
+            req.indices = host[0][i]
+            req.mask = None if rebuild else host[1][i]
+            req._rebuild = rebuild
             req.step, req.t_start, req.t_done = step, t_start, now
             self.finished.append(req)
         return served + len(reqs)

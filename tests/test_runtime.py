@@ -613,29 +613,183 @@ def test_cam_search_server_latency_stats_by_slo():
         float(np.percentile(waits, 99)))
 
 
+def _exact_store_cfg(match_type: str = "exact"):
+    from repro.core import (AppConfig, ArchConfig, CAMConfig, CircuitConfig,
+                            DeviceConfig)
+    if match_type == "exact":
+        return CAMConfig(
+            app=AppConfig(distance="hamming", match_type="exact",
+                          match_param=2, data_bits=1),
+            arch=ArchConfig(h_merge="and", v_merge="gather"),
+            circuit=CircuitConfig(rows=8, cols=8, cell_type="tcam",
+                                  sensing="exact"),
+            device=DeviceConfig(device="fefet"))
+    return CAMConfig(
+        app=AppConfig(distance="l1", match_type="threshold", match_param=6,
+                      data_bits=3),
+        arch=ArchConfig(h_merge="adder", v_merge="gather"),
+        circuit=CircuitConfig(rows=8, cols=8, cell_type="mcam",
+                              sensing="threshold"),
+        device=DeviceConfig(device="fefet"))
+
+
+def _check_counters(cfg, dense: bool):
+    """Three full steps, one part-filled, one on an empty queue: each
+    step counts the bytes of the padded batch's host copies."""
+    from repro.core import FunctionalSimulator
+    from repro.runtime import CAMSearchServer
+
+    sim = FunctionalSimulator(cfg)
+    state = sim.write(jax.random.uniform(KEY, (30, 16)))
+    srv = CAMSearchServer(sim, state, batch=4)
+    assert srv.counters == {"steps": 0, "searches": 0, "fetch_bytes": 0,
+                            "mask_steps": 0}
+    queries = np.asarray(jax.random.uniform(jax.random.PRNGKey(3),
+                                            (12, 16)))
+    reqs = [srv.submit(q) for q in queries]
+    srv.run()
+    k, padded_K = reqs[0].indices.shape[0], state.spec.padded_K
+    # (Q, k) int32 indices, and the (Q, padded_K) f32 mask where dense
+    step_bytes = 4 * (k + padded_K * dense) * 4
+    assert srv.counters == {"steps": 3, "searches": 12,
+                            "fetch_bytes": 3 * step_bytes,
+                            "mask_steps": 3 * dense}
+    # a part-filled step fetches its whole padded batch
+    srv.submit(queries[0])
+    srv.step()
+    assert srv.counters == {"steps": 4, "searches": 13,
+                            "fetch_bytes": 4 * step_bytes,
+                            "mask_steps": 4 * dense}
+    srv.step()                                   # empty queue: no step
+    assert srv.counters["steps"] == 4
+
+
 def test_cam_search_server_counters_count_steps_requests_and_bytes():
+    """Best match: a step copies only the (Q, k) indices."""
+    _check_counters(_cam_server_cfg(), dense=False)
+
+
+def test_cam_search_server_counters_on_exact_match_store():
+    """Exact match: a step copies the indices and the dense mask."""
+    _check_counters(_exact_store_cfg(), dense=True)
+
+
+def _serve_and_compare(sim, state, queries, batch=4):
+    """Serve ``queries`` and hold each request's ``mask`` to the row of a
+    direct ``sim.query`` of its step's padded batch under the step's key:
+    equal bit for bit, in dtype and in shape."""
+    from repro.runtime import CAMSearchServer
+
+    srv = CAMSearchServer(sim, state, batch=batch)
+    reqs = [srv.submit(q) for q in queries]
+    srv.run()
+    for step in range(srv.counters["steps"]):
+        rs = [r for r in reqs if r.step == step]
+        qs = np.zeros((batch, queries.shape[1]), np.float32)
+        qs[:len(rs)] = np.stack([r.query for r in rs])
+        want = sim.query(state, jnp.asarray(qs),
+                         key=jax.random.fold_in(srv.key, step),
+                         valid_count=len(rs))
+        for i, r in enumerate(rs):
+            row = np.asarray(want.mask[i])
+            np.testing.assert_array_equal(r.indices,
+                                          np.asarray(want.indices[i]))
+            assert r.mask.dtype == row.dtype and r.mask.shape == row.shape
+            assert np.array_equal(r.mask, row)
+    return srv, reqs
+
+
+_BEST_STORES = {
+    "f32_kernel": lambda c: c.replace(device=dict(variation="d2d"),
+                                      sim=dict(use_kernel=True)),
+    "int8_kernel": lambda c: c.replace(sim=dict(use_kernel=True)),
+    "d2d_row": lambda c: c.replace(device=dict(variation="d2d"),
+                                   sim=dict(d2d_fold="row")),
+    "c2c_grid": lambda c: c.replace(device=dict(variation="c2c"),
+                                    sim=dict(c2c_fold="grid")),
+    "c2c_bank": lambda c: c.replace(device=dict(variation="c2c"),
+                                    sim=dict(c2c_fold="bank")),
+    "ivf": lambda c: c.replace(sim=dict(prefilter="ivf")),
+    "cascade": lambda c: c.replace(sim=dict(prefilter="signature",
+                                            top_p_banks=2)),
+    "voting": lambda c: c.replace(arch=dict(h_merge="voting")),
+    "k_over_padded_K": lambda c: c.replace(app=dict(match_param=40)),
+    "facade": lambda c: c,
+}
+
+
+@pytest.mark.parametrize("store", sorted(_BEST_STORES))
+def test_best_match_served_mask_is_rebuilt_bit_for_bit(store):
+    """A best-match step copies only the indices; each request's mask,
+    rebuilt from them on read, equals the row ``sim.query`` returns."""
+    from repro.core import CAMASim, FunctionalSimulator
+
+    cfg = _BEST_STORES[store](_cam_server_cfg())
+    sim = (CAMASim if store == "facade" else FunctionalSimulator)(cfg)
+    state = sim.write(jax.random.uniform(KEY, (30, 16)), KEY)
+    nv = state.spec.nv
+    assert (state.perm is not None) == (store == "ivf")
+    assert store != "cascade" or cfg.sim.top_p_banks < nv
+    queries = np.asarray(jax.random.uniform(jax.random.PRNGKey(12),
+                                            (9, 16)))
+    srv, reqs = _serve_and_compare(sim, state, queries)
+    assert srv.counters["steps"] == 3 and srv.counters["mask_steps"] == 0
+    k = reqs[0].indices.shape[0]
+    assert srv.counters["fetch_bytes"] == 3 * 4 * k * 4
+    if store == "k_over_padded_K":
+        assert k > state.spec.padded_K
+        assert all((r.indices == -1).any() for r in reqs)
+
+
+@pytest.mark.parametrize("match_type", ["exact", "threshold"])
+def test_exact_and_threshold_stores_keep_the_fetched_dense_row(match_type):
+    """Exact and threshold masks carry every match: the step copies the
+    (Q, padded_K) mask and each request holds its fetched row."""
+    from repro.core import FunctionalSimulator
+
+    sim = FunctionalSimulator(_exact_store_cfg(match_type))
+    # two distinct rows, twenty copies each: a query that is one of them
+    # matches more rows than the k = 16 indices can hold
+    stored = np.tile(np.asarray(jax.random.uniform(KEY, (2, 16))), (20, 1))
+    state = sim.write(jnp.asarray(stored))
+    queries = stored[:9]
+    srv, reqs = _serve_and_compare(sim, state, queries)
+    assert srv.counters["mask_steps"] == srv.counters["steps"] == 3
+    for r in reqs:                        # a row view of the step's copy
+        assert r.mask.base is not None
+        assert r.mask.base.shape == (4, state.spec.padded_K)
+        assert r.mask is r.mask           # held, not rebuilt per read
+    assert all(r.mask.sum() == 20 > (r.indices >= 0).sum() for r in reqs)
+
+
+def test_best_match_request_holds_no_padded_K_array_and_mask_assigns():
+    """A finished best-match request keeps its (k,) indices and no array
+    of the store's height; ``mask`` stays assignable, and assigning it
+    (``None`` included) replaces what the request holds."""
     from repro.core import FunctionalSimulator
     from repro.runtime import CAMSearchServer
 
     sim = FunctionalSimulator(_cam_server_cfg())
     state = sim.write(jax.random.uniform(KEY, (30, 16)))
+    padded_K = state.spec.padded_K
     srv = CAMSearchServer(sim, state, batch=4)
-    assert srv.counters == {"steps": 0, "searches": 0, "fetch_bytes": 0}
-    queries = np.asarray(jax.random.uniform(jax.random.PRNGKey(3),
-                                            (12, 16)))
-    reqs = [srv.submit(q) for q in queries]
+    reqs = [srv.submit(q) for q in np.asarray(
+        jax.random.uniform(jax.random.PRNGKey(14), (4, 16)))]
     srv.run()
-    handed = sum(r.indices.nbytes + r.mask.nbytes for r in reqs)
-    assert srv.counters == {"steps": 3, "searches": 12,
-                            "fetch_bytes": handed}
-    # a part-filled step fetches its whole padded batch
-    srv.submit(queries[0])
-    srv.step()
-    k, padded_K = reqs[0].indices.shape[0], reqs[0].mask.shape[0]
-    assert srv.counters["steps"] == 4 and srv.counters["searches"] == 13
-    assert srv.counters["fetch_bytes"] == handed + 4 * (k + padded_K) * 4
-    srv.step()                                   # empty queue: no step
-    assert srv.counters["steps"] == 4
+    for r in reqs:
+        held = [v for name, v in vars(r).items()
+                if isinstance(v, np.ndarray) and name != "query"]
+        assert all(v.size < padded_K for v in held)
+        assert all(v.base is None or v.base.size < padded_K for v in held)
+        assert r.mask.shape == (padded_K,) and r.mask.sum() == 2
+        assert r.mask is not r.mask       # rebuilt on each read
+    reqs[0].mask = None
+    assert reqs[0].mask is None and reqs[0].done
+    row = np.ones(padded_K, np.float32)
+    reqs[1].mask = row
+    assert reqs[1].mask is row
+    reqs[1].mask = None
+    assert reqs[1].mask is None
 
 
 def test_cam_search_server_requests_carry_their_step_and_start():

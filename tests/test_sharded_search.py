@@ -362,6 +362,78 @@ def test_best_match_ties_across_shards_4_devices():
         (proc.stdout[-2000:], proc.stderr[-4000:])
 
 
+# the serve engine over the sharded backend: a best-match step copies only
+# the (Q, k) indices, and each served request's mask, rebuilt from them,
+# equals the row the sharded query returns, bit for bit; bank-padded grids
+# (nv = 5 on 4 devices), k > padded_K, voting and C2C included
+_SHARD_SERVE_SCRIPT = r'''
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+os.environ["JAX_PLATFORMS"] = "cpu"
+import jax, jax.numpy as jnp, numpy as np
+from repro.core import (AppConfig, ArchConfig, CAMASim, CAMConfig,
+                        CircuitConfig, DeviceConfig, ShardedCAMSimulator)
+from repro.launch.mesh import make_cam_mesh
+from repro.runtime import CAMSearchServer
+
+base = CAMConfig(
+    app=AppConfig(distance="l2", match_type="best", match_param=5,
+                  data_bits=3),
+    arch=ArchConfig(h_merge="adder", v_merge="comparator"),
+    circuit=CircuitConfig(rows=8, cols=8, cell_type="mcam", sensing="best"),
+    device=DeviceConfig(device="fefet", variation_std=0.8)).replace(
+        sim=dict(c2c_fold="bank"))
+cases = {
+    "adder": base,
+    "voting": base.replace(arch=dict(h_merge="voting")),
+    "k_over_padded_K": base.replace(app=dict(match_param=50)),
+    "c2c": base.replace(device=dict(variation="c2c")),
+    "kernel": base.replace(sim=dict(use_kernel=True)),
+    "facade": base.replace(sim=dict(backend="sharded", devices=4)),
+}
+stored = jax.random.uniform(jax.random.PRNGKey(0), (37, 16))
+queries = np.asarray(jax.random.uniform(jax.random.PRNGKey(1), (9, 16)))
+batch = 4
+n = 0
+for tag, cfg in cases.items():
+    sim = (CAMASim(cfg) if tag == "facade"
+           else ShardedCAMSimulator(cfg, make_cam_mesh(4)))
+    state = sim.write(stored)
+    assert state.spec.padded_K == 40 and state.grid.shape[0] == 8, tag
+    srv = CAMSearchServer(sim, state, batch=batch)
+    reqs = [srv.submit(q) for q in queries]
+    srv.run()
+    k = reqs[0].indices.shape[0]
+    assert srv.counters["mask_steps"] == 0, tag
+    assert srv.counters["fetch_bytes"] == 3 * batch * k * 4, tag
+    for step in range(3):
+        rs = [r for r in reqs if r.step == step]
+        qs = np.zeros((batch, 16), np.float32)
+        qs[:len(rs)] = np.stack([r.query for r in rs])
+        want = sim.query(state, jnp.asarray(qs),
+                         key=jax.random.fold_in(srv.key, step),
+                         valid_count=len(rs))
+        for i, r in enumerate(rs):
+            row = np.asarray(want.mask[i])
+            np.testing.assert_array_equal(r.indices,
+                                          np.asarray(want.indices[i]), tag)
+            assert r.mask.dtype == row.dtype, tag
+            assert r.mask.shape == row.shape == (40,), tag
+            assert np.array_equal(r.mask, row), tag
+    if tag == "k_over_padded_K":
+        assert k == 50 and all((r.indices == -1).any() for r in reqs)
+    n += 1
+print(f"SHARD_SERVE_OK {n}")
+'''
+
+
+@pytest.mark.multidevice
+def test_served_best_match_mask_rebuilt_on_sharded_backend_4_devices():
+    proc = _run_subprocess(_SHARD_SERVE_SCRIPT, timeout=600)
+    assert proc.returncode == 0 and "SHARD_SERVE_OK 6" in proc.stdout, \
+        (proc.stdout[-2000:], proc.stderr[-4000:])
+
+
 # ---------------------------------------------------------------------------
 # merge invariants (pure functions — no devices needed)
 # ---------------------------------------------------------------------------
